@@ -21,10 +21,10 @@ Three parts, all self-checking (non-zero exit on violation):
 
   **C. Roofline report** — the plane's representative jitted kernels
   (filter multiply/mask programs, projection accumulate, join probe) are
-  lowered abstractly at the benchmark row count and reported against the
-  TPU v5e roofline (``repro.launch.roofline``): elementwise relational
-  kernels should come out bandwidth-bound, which is what gates their
-  Pallas dispatch on TPU backends.
+  compiled from shapes at the benchmark row count and reported against
+  the published peaks of the device they compiled for
+  (``repro.launch.roofline.PEAKS``, keyed by ``device_kind``).  A device
+  without published peaks (the CPU) gets no roofline terms.
 
 Usage (from the repo root):
 
@@ -284,9 +284,17 @@ def run_session(rows: int = SESSION_ROWS, versions: int = SESSION_VERSIONS):
 
 
 def run_roofline(rows: int):
-    plane = get_plane("jax")
-    report = plane.roofline_report(rows)
-    print(f"roofline @ {rows} rows (TPU v5e model):")
+    import jax
+
+    from repro.launch.roofline import PEAKS
+
+    dev = jax.devices()[0]
+    where = f"{dev.platform} / {dev.device_kind} x{len(jax.devices())}"
+    if dev.device_kind not in PEAKS:
+        print(f"roofline @ {rows} rows: no published peaks for {where}; skipped")
+        return []
+    report = get_plane("jax").roofline_report(rows)
+    print(f"roofline @ {rows} rows on {where}:")
     for r in report:
         print(
             f"  {r['kernel']:<12} flops {r['flops']:>12.3g}  "
@@ -330,7 +338,9 @@ def main() -> None:
                     help=f"left-source rows (default {FULL_ROWS}; "
                          f"smoke {SMOKE_ROWS})")
     args = ap.parse_args()
+    from repro.engine.plane.jax_plane import use_compile_cache
 
+    use_compile_cache(ROOT)
     rows = args.rows or (SMOKE_ROWS if args.smoke else FULL_ROWS)
     headline = run_chain(rows)
     headline.update(run_session())

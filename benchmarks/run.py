@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import pathlib
 import sys
 import time
 
@@ -29,6 +30,9 @@ def main() -> None:
         help="write the section summaries as JSON to this path",
     )
     args = ap.parse_args()
+    from repro.engine.plane.jax_plane import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).resolve().parent.parent)
     from benchmarks import (
         chain_bench,
         delta_bench,

@@ -284,6 +284,10 @@ def main() -> None:
     config = config.replace(plane=args.plane, fleet=args.fleet,
                             shared_tier=args.shared_tier,
                             exec_mode=args.exec_mode).validate()
+    if args.plane == "jax":
+        from repro.engine.plane.jax_plane import use_compile_cache
+
+        use_compile_cache(ROOT)
 
     result, headline, rows = run(
         config,

@@ -126,11 +126,13 @@ def _window_jaxpr(dag: DataflowDAG, sinks: List[str]) -> str:
             out.append(mask)
         return tuple(out)
 
+    # abstract avals: tracing never allocates, so verifying a window never
+    # initializes a device backend (the chip belongs to one process)
+    col = jax.ShapeDtypeStruct((_SYMBOLIC_ROWS,), jnp.float32)
+    mask = jax.ShapeDtypeStruct((_SYMBOLIC_ROWS,), jnp.bool_)
     avals = []
     for sid in src_ids:
-        sch = schemas[sid]
-        for _ in sch:
-            avals.append(jnp.zeros((_SYMBOLIC_ROWS,), jnp.float32))
-        avals.append(jnp.zeros((_SYMBOLIC_ROWS,), bool))
+        avals.extend([col] * len(schemas[sid]))
+        avals.append(mask)
     jaxpr = jax.make_jaxpr(fn)(*avals)
     return str(jaxpr)

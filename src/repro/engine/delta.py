@@ -293,6 +293,7 @@ def _execute_delta_pinned(
     q_in = [fetch_exact(i) for i in in_ids]
     t_p = fetch_p(b_q)
     t0 = time.perf_counter()
+    dispatches = plane.device_dispatches()
     if q_op.op_type == D.FILTER:
         state = _boundary_filter(q_op, p_op, q_in[0], t_p, plane, stats)
     elif dplan.klass == PROJECT_COLS:
@@ -303,6 +304,7 @@ def _execute_delta_pinned(
         raise DeltaUnsupported(f"no boundary rule for {dplan.klass}")
     state = _normalize(state, t_p)
     stats.ops_delta += 1
+    stats.ops_on_device += plane.device_dispatches() > dispatches
     sink_table = _store_spine_output(store, stats, q_digests, b_q, state,
                                      t_p, time.perf_counter() - t0)
 
@@ -320,6 +322,7 @@ def _execute_delta_pinned(
             else:
                 side[l.dst_port] = fetch_exact(l.src)
         t0 = time.perf_counter()
+        dispatches = plane.device_dispatches()
         state, dense_exec = _transition(
             op, state, t_p_in, t_p_out, side, spine_port, plane, stats
         )
@@ -328,6 +331,7 @@ def _execute_delta_pinned(
             stats.ops_executed += 1
         else:
             stats.ops_delta += 1
+        stats.ops_on_device += plane.device_dispatches() > dispatches
         sink_table = _store_spine_output(store, stats, q_digests, q_id,
                                          state, t_p_out,
                                          time.perf_counter() - t0)

@@ -58,6 +58,9 @@ class ExecStats:
     of a reused result, or off the requested outputs).
     ``delta_rows_processed`` sums the delta rows (inserts + deletes) the
     delta rules touched — the O(|Δ|) work that replaced full re-execution.
+    ``ops_lowered`` counts operators the plane took instead of the
+    reference, host-vectorized ones included; ``ops_on_device`` counts the
+    operators whose work ran in a jitted kernel on the default device.
     ``tables_served`` is the subset of reuses fetched from the
     ``MaterializationStore``; ``recompute_time_saved`` sums the recorded
     original compute cost of every served table (``perf_counter``-based,
@@ -72,6 +75,7 @@ class ExecStats:
     delta_rows_processed: int = 0
     plane: str = "numpy"
     ops_lowered: int = 0
+    ops_on_device: int = 0
     tables_served: int = 0
     store_writes: int = 0
     store_dedup_skipped: int = 0
@@ -265,7 +269,11 @@ class ExecutionPlan:
                 else:
                     ins = [results[l.src] for l in self.dag.in_links[op_id]]
                     stats.ops_lowered += self.plane.lowers(op, ins)
+                    before = self.plane.device_dispatches()
                     table = self.plane.execute_op(op, ins)
+                    stats.ops_on_device += (
+                        self.plane.device_dispatches() > before
+                    )
                 elapsed = time.perf_counter() - t0
                 stats.ops_executed += 1
                 if materialize and digests[op_id] is not None:

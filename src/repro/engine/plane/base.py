@@ -11,6 +11,9 @@ certificates and the reuse frontier are plane-agnostic.  The contract:
     vectorized lowering distinct from the reference implementation —
     pure accounting (``ExecStats.ops_lowered``), never a correctness
     signal;
+  * ``device_dispatches()`` counts, per thread, the calls whose work ran
+    in a jitted kernel on the default device — the accounting behind
+    ``ExecStats.ops_on_device``; host-only planes keep it at 0;
   * planes hold no per-run mutable state: one instance is shared by every
     session/thread of a process (the registry memoizes instances), so any
     internal caches must be idempotent under racing writers.
@@ -46,6 +49,11 @@ class DataPlane(abc.ABC):
     @abc.abstractmethod
     def execute_op(self, op: D.Operator, inputs: List[Table]) -> Table:
         """Execute one operator; bytes must match the reference plane."""
+
+    def device_dispatches(self) -> int:
+        """Monotonic count of this thread's calls into this plane whose
+        work ran in a jitted kernel on the default device."""
+        return 0
 
     def pred_mask(self, pred, table: Table):
         """Boolean keep-mask of ``pred`` over ``table`` — the delta-kernel

@@ -20,9 +20,11 @@ canonical numpy bytes).  Three design rules make that possible:
    standalone multiply must be correctly rounded), and an
    *accumulate/compare/combine* program containing no multiplies at all —
    nothing left to contract, so it is exact by construction.  A one-time
-   self-probe (``_values_ok``) verifies this on adversarial data at first
-   use and disables the jitted value path entirely if the backend ever
-   diverges.
+   self-probe (``values_exact``) verifies this on adversarial data at first
+   use.  If the backend diverges, the plane records the first differing
+   value (``probe_mismatch``), warns once, and runs FILTER and PROJECT on
+   the host for the rest of the process.  Errors from compiling or running
+   a kernel are never caught: they propagate to the caller.
 
 3. **Everything unsupported falls back per-operator** to the reference
    plane (object-dtype columns, string/opaque predicates, UDFs, ...) —
@@ -33,8 +35,9 @@ Lowering map (see ``docs/DATA_PLANE.md`` for the rationale per row):
   FILTER      fused two-program predicate kernel (LinCmp trees; StrEq /
               NonLinearAtom masks evaluated host-side and fused in)
   PROJECT     fused two-program linear-expression kernel
-  JOIN        joint unique-compression of key columns + jitted
-              stable-argsort/searchsorted probe; host np.repeat expansion
+  JOIN        joint unique-compression of key columns; dense codes probe
+              a host bincount table, sparse codes a host stable argsort
+              plus a jitted searchsorted probe; host np.repeat expansion
   AGGREGATE   group codes + stable argsort into contiguous segments;
               per-group reductions on contiguous float64 slices (same
               pairwise summation as the reference)
@@ -49,6 +52,8 @@ Lowering map (see ``docs/DATA_PLANE.md`` for the rationale per row):
 
 from __future__ import annotations
 
+import threading
+import warnings
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -65,14 +70,19 @@ _AGG_FNS = ("count", "sum", "min", "max", "avg")
 
 
 def _modules():
-    """Lazy jax import: (jax, jnp, enable_x64) or PlaneError if unusable."""
+    """Lazy jax import: (jax, jnp) or PlaneError if jax is not installed."""
     try:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
-    except Exception as e:  # pragma: no cover - exercised on jax-less hosts
+    except ImportError as e:  # pragma: no cover - exercised on jax-less hosts
         raise PlaneError(f"jax backend unavailable: {e}") from e
-    return jax, jnp, enable_x64
+    return jax, jnp
+
+
+def _x64():
+    """Scope in which the plane's kernels trace and run in 64 bits."""
+    jax, _ = _modules()
+    return jax.enable_x64(True)
 
 
 class _PredPlan:
@@ -121,11 +131,24 @@ class JaxPlane(DataPlane):
         self._pred_plans: Dict[str, object] = {}
         self._proj_plans: Dict[str, object] = {}
         self._join_probe = None
-        self._exact: Optional[bool] = None
+        self._probe_lock = threading.Lock()
+        self._dispatch = threading.local()
+        #: exactness probe outcome: None until it runs, then True/False
+        self.exact: Optional[bool] = None
+        #: first value where a probed kernel differed from the reference
+        self.probe_mismatch: Optional[str] = None
+
+    def device_dispatches(self) -> int:
+        return getattr(self._dispatch, "n", 0)
+
+    def _dispatched(self) -> None:
+        self._dispatch.n = self.device_dispatches() + 1
 
     # -- protocol -------------------------------------------------------------
     def lowers(self, op: D.Operator, inputs: List[Table]) -> bool:
         t = op.op_type
+        if t in (D.FILTER, D.PROJECT) and not self.values_exact():
+            return False
         try:
             if t == D.FILTER:
                 plan = self._pred_plan(op.get("pred"))
@@ -201,20 +224,32 @@ class JaxPlane(DataPlane):
         raise AssertionError(f"lowers/execute_op disagree on {t}")
 
     # -- FILTER / PROJECT: fused two-program kernels --------------------------
-    def _values_ok(self) -> bool:
+    def values_exact(self) -> bool:
         """One-time self-probe: the compiled two-program kernels must be
         bit-identical to the reference on adversarial (uniform-float) data.
-        Exact by construction on a correct backend; any divergence (e.g. a
-        backend that FMA-contracts across program boundaries) disables the
-        jitted filter/project value path for the whole process."""
-        if self._exact is None:
-            try:
-                self._exact = self._run_exactness_probe()
-            except Exception:
-                self._exact = False
-        return self._exact
+        Exact by construction on a correct backend; a divergence (e.g. a
+        backend that FMA-contracts across program boundaries, or a float64
+        emulation that rounds differently) sends FILTER and PROJECT to the
+        host for the whole process, with ``exact``/``probe_mismatch`` set
+        and one ``RuntimeWarning``."""
+        if self.exact is None:
+            with self._probe_lock:
+                if self.exact is None:
+                    self.probe_mismatch = self._run_exactness_probe()
+                    self.exact = self.probe_mismatch is None
+                    if not self.exact:
+                        warnings.warn(
+                            "jax plane exactness probe failed "
+                            f"({self.probe_mismatch}); FILTER and PROJECT "
+                            "run on the host",
+                            RuntimeWarning,
+                            stacklevel=2,
+                        )
+        return self.exact
 
-    def _run_exactness_probe(self) -> bool:
+    def _run_exactness_probe(self) -> Optional[str]:
+        """None when every probed value is bit-identical, else a
+        description of the first one that is not."""
         from fractions import Fraction
 
         from repro.core.predicates import LinExpr
@@ -229,28 +264,32 @@ class JaxPlane(DataPlane):
         e2 = LinExpr.make({"b": Fraction(1, 3), "c": 2}, Fraction(-1, 2))
         pred = Pred.and_(Pred.of(LinCmp(e1, "<=")), Pred.of(LinCmp(e2, "<")))
         plan = self._compile_pred(pred)
-        got_mask = self._eval_pred_plan(plan, t)
-        if not np.array_equal(got_mask, eval_pred(pred, t)):
-            return False
+        got = self._eval_pred_plan(plan, t)
+        bad = _first_mismatch("filter mask", got, eval_pred(pred, t))
+        if bad:
+            return bad
         cols = (("x", e1), ("y", e2), ("b", "b"))
         pplan = self._compile_proj(cols)
-        got = self._eval_proj_plan(pplan, t)
+        proj = self._eval_proj_plan(pplan, t)
         for name, expr in cols:
             want = t.cols[expr] if isinstance(expr, str) else eval_linexpr(expr, t)
-            if not np.array_equal(got.cols[name], want, equal_nan=True):
-                return False
-        return True
+            bad = _first_mismatch(f"project column {name}", proj.cols[name], want)
+            if bad:
+                return bad
+        return None
 
     def _pred_plan(self, pred: Pred):
+        if not self.values_exact():
+            return None
         key = repr(pred)
         plan = self._pred_plans.get(key)
         if plan is None:
-            plan = (self._compile_pred(pred) or _NO_PLAN) if self._values_ok() else _NO_PLAN
+            plan = self._compile_pred(pred) or _NO_PLAN
             self._pred_plans[key] = plan
         return None if plan is _NO_PLAN else plan
 
     def _compile_pred(self, pred: Pred) -> Optional[_PredPlan]:
-        jax, jnp, _ = _modules()
+        _, jnp = _modules()
         from repro.kernels.relational import build_elementwise
 
         lin_atoms: List[LinCmp] = []
@@ -345,15 +384,15 @@ class JaxPlane(DataPlane):
         )
 
     def _eval_pred_plan(self, plan: _PredPlan, t: Table) -> np.ndarray:
-        _, _, enable_x64 = _modules()
         hosts = [eval_pred(Pred.of(a), t) for a in plan.host_atoms]
-        with enable_x64():
+        with _x64():
             prods = plan.mul(*[t.cols[c] for c, _ in plan.prods_spec])
             out = plan.mask(*prods, *hosts)
         return np.asarray(out)
 
     def _filter(self, op: D.Operator, inputs: List[Table]) -> Table:
         plan = self._pred_plan(op.get("pred"))
+        self._dispatched()
         return inputs[0].mask(self._eval_pred_plan(plan, inputs[0]))
 
     def pred_mask(self, pred, t: Table):
@@ -362,19 +401,22 @@ class JaxPlane(DataPlane):
         either way bit-identical to ``eval_pred`` (probed at compile)."""
         plan = self._pred_plan(pred)
         if plan is not None and _numeric(t, plan.lin_cols):
+            self._dispatched()
             return self._eval_pred_plan(plan, t)
         return eval_pred(pred, t)
 
     def _proj_plan(self, cols):
+        if not self.values_exact():
+            return None
         key = repr(cols)
         plan = self._proj_plans.get(key)
         if plan is None:
-            plan = (self._compile_proj(cols) or _NO_PLAN) if self._values_ok() else _NO_PLAN
+            plan = self._compile_proj(cols) or _NO_PLAN
             self._proj_plans[key] = plan
         return None if plan is _NO_PLAN else plan
 
     def _compile_proj(self, cols) -> Optional[_ProjPlan]:
-        jax, jnp, _ = _modules()
+        _, jnp = _modules()
         from repro.kernels.relational import build_elementwise
 
         prods_spec: List[Tuple[str, float]] = []
@@ -415,8 +457,7 @@ class JaxPlane(DataPlane):
         )
 
     def _eval_proj_plan(self, plan: _ProjPlan, src: Table) -> Table:
-        _, _, enable_x64 = _modules()
-        with enable_x64():
+        with _x64():
             prods = plan.mul(*[src.cols[c] for c, _ in plan.prods_spec])
             vals = plan.val(*prods)
         vals = vals if isinstance(vals, (tuple, list)) else (vals,)
@@ -431,12 +472,13 @@ class JaxPlane(DataPlane):
 
     def _project(self, op: D.Operator, inputs: List[Table]) -> Table:
         plan = self._proj_plan(op.get("cols"))
+        self._dispatched()
         return self._eval_proj_plan(plan, inputs[0])
 
     # -- JOIN: device probe over unique-compressed keys -----------------------
     def _probe(self):
         if self._join_probe is None:
-            jax, _, _ = _modules()
+            jax, _ = _modules()
             self._join_probe = jax.jit(_join_probe_body)
         return self._join_probe
 
@@ -470,34 +512,33 @@ class JaxPlane(DataPlane):
         #     case since per-column codes come compressed): a bincount +
         #     exclusive-cumsum lookup table — O(1) gathers per left row, no
         #     per-query binary search;
-        #   * sparse codes: the jitted stable-argsort/searchsorted kernel,
-        #     with inputs bucket-padded by a sentinel above every possible
-        #     code (codes stay < 2**61; see combine_codes) so jit compiles
-        #     once per power-of-two bucket, not once per row count.
-        #     Sentinels sort to the tail and no real key's window can reach
-        #     them.
+        #   * sparse codes: the jitted searchsorted probe of the left keys
+        #     into the sorted right keys.  The stable argsort stays on the
+        #     host: XLA:TPU takes minutes to compile a sort at these sizes,
+        #     and a binary search seconds.  Both operands are bucket-padded
+        #     by a sentinel above every possible code (codes stay < 2**61;
+        #     see combine_codes) so jit compiles once per power-of-two
+        #     bucket, not once per row count.  Sentinels sit at the tail of
+        #     the sorted keys and no real key's window can reach them.
         max_code = int(joint.max()) if joint.size else 0
+        order = np.argsort(rk, kind="stable")
         if max_code <= max(1 << 22, 4 * (nl + nr)):
-            order = np.argsort(rk, kind="stable")
             counts_all = np.bincount(rk, minlength=max_code + 1)
             ends_all = np.cumsum(counts_all)
             lo = (ends_all - counts_all)[lk]
             hi = ends_all[lk]
         else:
-            _, jnp, enable_x64 = _modules()
+            _, jnp = _modules()
             from repro.kernels.relational import pow2_bucket
 
             sentinel = np.int64(1) << 62
-            bl, br = pow2_bucket(nl), pow2_bucket(nr)
-            lk_p = np.full(bl, sentinel, dtype=np.int64)
+            lk_p = np.full(pow2_bucket(nl), sentinel, dtype=np.int64)
             lk_p[:nl] = lk
-            rk_p = np.full(br, sentinel, dtype=np.int64)
-            rk_p[:nr] = rk
-            with enable_x64():
-                order, lo, hi = self._probe()(
-                    jnp.asarray(lk_p), jnp.asarray(rk_p)
-                )
-            order = np.asarray(order)
+            sr_p = np.full(pow2_bucket(nr), sentinel, dtype=np.int64)
+            sr_p[:nr] = rk[order]
+            self._dispatched()
+            with _x64():
+                lo, hi = self._probe()(jnp.asarray(lk_p), jnp.asarray(sr_p))
             lo = np.asarray(lo)[:nl]
             hi = np.asarray(hi)[:nl]
 
@@ -660,40 +701,46 @@ class JaxPlane(DataPlane):
         return src.with_col(out, (h % k).astype(np.float64))
 
     # -- reporting ------------------------------------------------------------
-    def roofline_report(self, n: int = 1_000_000) -> List[Dict]:
-        """Roofline terms for the plane's representative jitted kernels at
-        ``n`` rows (consumed by ``benchmarks/plane_bench.py``).  Kernels are
-        lowered abstractly (``ShapeDtypeStruct``) — no device allocation."""
+    def kernel_specs(self, n: int) -> List[Tuple[str, object, list]]:
+        """The plane's representative jitted kernels as ``(name, body,
+        avals)`` at ``n`` rows; trace or compile them under ``jax.enable_x64``."""
         from fractions import Fraction
 
         from repro.core.predicates import LinExpr
-        from repro.launch.roofline import kernel_roofline
 
-        jax, jnp, enable_x64 = _modules()
+        jax, _ = _modules()
         e1 = LinExpr.make({"a": Fraction(5, 2), "b": -1}, 1)
         e2 = LinExpr.make({"c": Fraction(1, 3)}, Fraction(-1, 2))
         pred = Pred.and_(Pred.of(LinCmp(e1, "<=")), Pred.of(LinCmp(e2, "<")))
         pplan = self._compile_pred(pred)
         jplan = self._compile_proj((("x", e1), ("y", e2)))
+        f64 = jax.ShapeDtypeStruct((n,), np.float64)
+        i64 = jax.ShapeDtypeStruct((n,), np.int64)
+        return [
+            ("filter_mul", pplan.mul_body, [f64] * len(pplan.prods_spec)),
+            ("filter_mask", pplan.mask_body, [f64] * len(pplan.prods_spec)),
+            ("project_sum", jplan.val_body, [f64] * len(jplan.prods_spec)),
+            ("join_probe", _join_probe_body, [i64, i64]),
+        ]
+
+    def roofline_report(self, n: int = 1_000_000) -> List[Dict]:
+        """Roofline terms of ``kernel_specs(n)`` on the default device
+        (consumed by ``benchmarks/plane_bench.py``); a device without
+        published peaks raises ``UnknownDevice``.  Kernels are compiled
+        from shapes — no device allocation."""
+        from repro.launch.roofline import kernel_roofline
+
+        jax, _ = _modules()
+        kind = jax.devices()[0].device_kind
         report: List[Dict] = []
-        with enable_x64():
-            f64 = jax.ShapeDtypeStruct((n,), jnp.float64)
-            i64 = jax.ShapeDtypeStruct((n,), jnp.int64)
-            kernels = [
-                ("filter_mul", pplan.mul_body,
-                 [f64] * len(pplan.prods_spec)),
-                ("filter_mask", pplan.mask_body,
-                 [f64] * len(pplan.prods_spec)),
-                ("project_sum", jplan.val_body,
-                 [f64] * len(jplan.prods_spec)),
-                ("join_probe", _join_probe_body, [i64, i64]),
-            ]
-            for name, fn, args in kernels:
-                r = kernel_roofline(fn, *args)
+        with _x64():
+            for name, fn, args in self.kernel_specs(n):
+                r = kernel_roofline(fn, *args, device_kind=kind)
                 report.append(
                     {
                         "kernel": name,
                         "rows": n,
+                        "device_kind": kind,
                         "flops": r.flops,
                         "hbm_bytes": r.hbm_bytes,
                         "t_compute_s": r.t_compute,
@@ -705,20 +752,54 @@ class JaxPlane(DataPlane):
         return report
 
 
-def _join_probe_body(lk, rk):
-    """Sorted-probe join kernel: stable argsort + two searchsorteds.
-
-    With int64 code inputs this is bit-identical to the numpy pair (both
-    implement the same stable comparison sort contract on total-ordered
-    integers), so the expansion host-side reproduces reference bytes.
-    """
+def _join_probe_body(lk, sr):
+    """Sorted-probe join kernel: each left key's window ``[lo, hi)`` in the
+    sorted right keys ``sr``.  Exact on int64 codes (a binary search over
+    total-ordered integers), so the host-side expansion reproduces the
+    reference bytes."""
     import jax.numpy as jnp
 
-    order = jnp.argsort(rk, stable=True)
-    sr = rk[order]
     lo = jnp.searchsorted(sr, lk, side="left")
     hi = jnp.searchsorted(sr, lk, side="right")
-    return order, lo, hi
+    return lo, hi
+
+
+def use_compile_cache(root) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is configured here.  Otherwise the cache goes to
+    ``<root>/.jax_cache``: one fixed path, because the path is part of
+    what a later process must match to find the entries.  Called by entry
+    points (``chip_smoke.py``, the benchmarks), never on import.
+    """
+    import os
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax, _ = _modules()
+    path = os.path.join(os.fspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def _first_mismatch(what: str, got: np.ndarray, want: np.ndarray) -> Optional[str]:
+    """None if ``got`` is bit-identical to ``want``, else where it is not."""
+    got, want = np.asarray(got), np.asarray(want)
+    if got.shape != want.shape or got.dtype != want.dtype:
+        return (f"{what}: kernel {got.dtype}{got.shape}, "
+                f"reference {want.dtype}{want.shape}")
+    if got.dtype.kind == "f":  # compare bits: -0.0 vs 0.0 and NaNs count
+        got_b, want_b = got.view(f"u{got.itemsize}"), want.view(f"u{want.itemsize}")
+        bad = np.flatnonzero(got_b != want_b)
+    else:
+        bad = np.flatnonzero(got != want)
+    if not len(bad):
+        return None
+    i = int(bad[0])
+    return (f"{what} row {i}: kernel {got[i]!r}, reference {want[i]!r} "
+            f"({len(bad)} of {len(got)} rows differ)")
 
 
 def _numeric(t: Table, cols) -> bool:

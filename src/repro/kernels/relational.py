@@ -1,37 +1,25 @@
-"""Fused elementwise kernels for the relational data plane.
+"""Jitted elementwise kernels for the relational data plane.
 
 The jax plane's filter/project kernels are pure elementwise programs
 (adds, compares, boolean combine — the multiply program is jitted
-separately; see ``engine/plane/jax_plane.py``).  This module gives them
-the same dispatch policy as the other repo kernels (``kernels/ops.py``):
+separately; see ``engine/plane/jax_plane.py``).  ``build_elementwise``
+runs such a body as one ``jax.jit`` program on the default device,
+zero-padding every operand to a power-of-two bucket so one compile serves
+every row count in the bucket.
 
-  * "auto"      — Pallas kernel on TPU backends *when the roofline says
-                  the program is bandwidth-bound* (elementwise relational
-                  bodies essentially always are: zero dot-flops, pure
-                  streaming), jitted jnp elsewhere.
-  * "pallas"    — force the Pallas lowering (TPU).
-  * "interpret" — Pallas kernel body in interpret mode (CPU tests).
-  * "reference" — plain ``jax.jit`` of the body.
-
-The Pallas lowering pads each 1-D operand to a multiple of
-``block_rows * lane`` (8×128 — the float32 TPU tile), reshapes to
-``(rows, lane)`` and runs an elementwise grid over row blocks.  Bodies
-must be elementwise (no reductions, no cross-row communication) so block
-decomposition is trivially exact; exactness of the *values* is the
-plane's concern (its bodies contain no multiplies, so there is nothing
-for XLA to FMA-contract).
+The plane calls these kernels under x64 with float64 operands, which is
+what its byte-identity contract needs.  A Pallas lowering cannot serve
+them: Mosaic refuses 64-bit element types on TPU, so an exact Pallas
+formulation would have to be float32 or integer.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Sequence
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-_BLOCK_ROWS = 8
-_LANE = 128
 
 
 def pow2_bucket(n: int) -> int:
@@ -50,116 +38,25 @@ def _pad_to(a, b: int):
     return jnp.pad(a, (0, b - n), constant_values=pad_val)
 
 
-def _default_impl(body: Callable, arrs: Sequence) -> str:
-    try:
-        plat = jax.default_backend()
-    except Exception:  # pragma: no cover
-        plat = "cpu"
-    if plat != "tpu":
-        return "reference"
-    try:
-        from repro.launch.roofline import is_bandwidth_bound
-
-        return "pallas" if is_bandwidth_bound(body, *arrs) else "reference"
-    except Exception:  # pragma: no cover - analysis failure = safe default
-        return "reference"
-
-
-def build_elementwise(body: Callable, *, impl: str = "auto") -> Callable:
-    """A cached callable running ``body`` under the dispatch policy.
+def build_elementwise(body: Callable) -> Callable:
+    """A callable running ``body`` as one jitted program.
 
     ``body`` maps 1-D arrays to a 1-D array or tuple of 1-D arrays, all of
     one common length.  The returned callable accepts numpy or jax arrays
-    and returns numpy.  Dispatch for ``"auto"`` is resolved once, on the
-    first call (the roofline check needs sample operands); the resolution
-    is idempotent, so racing first calls are benign.
+    and returns numpy.  Operands are zero-padded to a power-of-two bucket:
+    bodies are elementwise (a pad lane never influences a real lane), so
+    slicing the outputs back to n is exact, and the jit cache is hit for
+    every row count in the same bucket.
     """
-    state: dict = {}
+    jitted = jax.jit(body)
 
     def call(*arrs):
-        fn = state.get("fn")
-        if fn is None:
-            mode = impl if impl != "auto" else _default_impl(body, arrs)
-            if mode in ("pallas", "interpret"):
-                interp = mode == "interpret"
-
-                def fn(*xs):
-                    return _elementwise_pallas(body, xs, interpret=interp)
-
-            else:
-                jitted = jax.jit(body)
-
-                # zero-pad to power-of-two buckets: bodies are elementwise
-                # (a pad lane never influences a real lane), so slicing the
-                # outputs back to n is exact, and the jit cache is hit for
-                # every row count in the same bucket
-                def fn(*xs):
-                    xs = [jnp.asarray(x) for x in xs]
-                    n = int(xs[0].shape[0])
-                    b = pow2_bucket(n)
-                    out = jitted(*[_pad_to(x, b) for x in xs])
-                    if isinstance(out, (tuple, list)):
-                        return tuple(np.asarray(o)[:n] for o in out)
-                    return np.asarray(out)[:n]
-
-            state["fn"] = fn
-        return fn(*arrs)
+        xs = [jnp.asarray(x) for x in arrs]
+        n = int(xs[0].shape[0])
+        b = pow2_bucket(n)
+        out = jitted(*[_pad_to(x, b) for x in xs])
+        if isinstance(out, (tuple, list)):
+            return tuple(np.asarray(o)[:n] for o in out)
+        return np.asarray(out)[:n]
 
     return call
-
-
-def _elementwise_pallas(
-    body: Callable,
-    arrays: Sequence,
-    *,
-    interpret: bool,
-    block_rows: int = _BLOCK_ROWS,
-    lane: int = _LANE,
-):
-    from jax.experimental import pallas as pl
-
-    arrays = [jnp.asarray(a) for a in arrays]
-    n = int(arrays[0].shape[0])
-    tile = block_rows * lane
-    m = max(1, -(-n // tile))  # ceil; one padding block for n == 0
-    padded = m * tile
-
-    blocks = []
-    for a in arrays:
-        pad_val = False if a.dtype == jnp.bool_ else 0
-        a = jnp.pad(a, (0, padded - n), constant_values=pad_val)
-        blocks.append(a.reshape(m * block_rows, lane))
-
-    out_shape = jax.eval_shape(
-        body,
-        *[
-            jax.ShapeDtypeStruct((block_rows, lane), a.dtype)
-            for a in blocks
-        ],
-    )
-    single = not isinstance(out_shape, (tuple, list))
-    outs = (out_shape,) if single else tuple(out_shape)
-    n_in = len(blocks)
-
-    def kernel(*refs):
-        ins, out_refs = refs[:n_in], refs[n_in:]
-        res = body(*[r[...] for r in ins])
-        res = (res,) if not isinstance(res, (tuple, list)) else tuple(res)
-        for o, r in zip(out_refs, res):
-            o[...] = r
-
-    spec = pl.BlockSpec((block_rows, lane), lambda i: (i, 0))
-    result = pl.pallas_call(
-        kernel,
-        grid=(m,),
-        in_specs=[spec for _ in blocks],
-        out_specs=tuple(spec for _ in outs),
-        out_shape=tuple(
-            jax.ShapeDtypeStruct((m * block_rows, lane), o.dtype)
-            for o in outs
-        ),
-        interpret=interpret,
-    )(*blocks)
-
-    unpacked = tuple(np.asarray(r).reshape(-1)[:n] for r in result)
-    return unpacked[0] if single else unpacked

@@ -167,7 +167,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: pathlib.Path,
         f"[{arch} × {shape_name} × {mesh_name}] COST: flops={ca.get('flops', 0):.3e} "
         f"bytes={ca.get('bytes accessed', 0):.3e}"
     )
-    rl = RL.roofline_from_compiled(compiled, model_flops=model_flops, n_chips=n_chips)
+    rl = RL.roofline_from_compiled(
+        compiled, model_flops=model_flops, n_chips=n_chips, device_kind=RL.V5E
+    )
 
     per_dev_bytes = (
         getattr(mem, "argument_size_in_bytes", 0)

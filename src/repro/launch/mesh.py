@@ -9,15 +9,10 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType landed after 0.4.x; Auto is the default there anyway
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _make_mesh(shape, axes):
-    if _AXIS_TYPE is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
-        shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

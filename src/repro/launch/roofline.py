@@ -36,10 +36,37 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-# TPU v5e hardware constants (assignment)
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float   # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # interconnect bytes/s per link
+
+
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+#: TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 819 GB/s HBM; ICI as 50 GB/s per link.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: the chip the multi-pod dry run models (``launch/dryrun.py``)
+V5E = "TPU v5 lite"
+
+
+class UnknownDevice(ValueError):
+    """No published peaks for this ``device_kind``: no time or share can
+    be computed for it."""
+
+
+def peaks(device_kind: str) -> Peaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no peaks for device kind {device_kind!r}; known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -293,6 +320,7 @@ class Roofline:
     collective_bytes: float    # per-chip collective operand bytes
     model_flops: float         # 6·N·D (train) / 2·N·B (decode), N_active
     n_chips: int
+    device_kind: str           # key into PEAKS; unknown kinds raise
     raw_cost_flops: float = 0.0
     raw_cost_bytes: float = 0.0
     collective_detail: Optional[Dict[str, float]] = None
@@ -300,15 +328,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind).flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / peaks(self.device_kind).ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -328,7 +356,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Achieved fraction of the compute roofline: time to do the USEFUL
         flops at peak vs. the dominant-term time of the compiled program."""
-        t_useful = (self.model_flops / self.n_chips) / PEAK_FLOPS
+        t_useful = (self.model_flops / self.n_chips) / peaks(self.device_kind).flops
         t_bound = max(self.t_compute, self.t_memory, self.t_collective)
         return t_useful / t_bound if t_bound else 0.0
 
@@ -341,6 +369,7 @@ class Roofline:
             "raw_cost_bytes": self.raw_cost_bytes,
             "model_flops": self.model_flops,
             "n_chips": self.n_chips,
+            "device_kind": self.device_kind,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,
@@ -352,7 +381,9 @@ class Roofline:
         }
 
 
-def roofline_from_compiled(compiled, *, model_flops: float, n_chips: int) -> Roofline:
+def roofline_from_compiled(
+    compiled, *, model_flops: float, n_chips: int, device_kind: str
+) -> Roofline:
     ca = compiled.cost_analysis()
     if isinstance(ca, list):
         ca = ca[0]
@@ -363,6 +394,7 @@ def roofline_from_compiled(compiled, *, model_flops: float, n_chips: int) -> Roo
         collective_bytes=an.total_collective_bytes,
         model_flops=model_flops,
         n_chips=n_chips,
+        device_kind=device_kind,
         raw_cost_flops=float(ca.get("flops", 0.0)),
         raw_cost_bytes=float(ca.get("bytes accessed", 0.0)),
         collective_detail=an.collective_bytes,
@@ -382,7 +414,7 @@ def analyze_jitted(fn, *args) -> HLOAnalysis:
     return analyze_hlo(compiled.as_text())
 
 
-def kernel_roofline(fn, *args) -> Roofline:
+def kernel_roofline(fn, *args, device_kind: str) -> Roofline:
     """Single-chip roofline for one jitted kernel (data-plane reporting).
 
     The fused-program traffic model (module docstring) counts only interior
@@ -409,20 +441,10 @@ def kernel_roofline(fn, *args) -> Roofline:
         collective_bytes=an.total_collective_bytes,
         model_flops=an.flops,
         n_chips=1,
+        device_kind=device_kind,
         collective_detail=an.collective_bytes,
         collective_counts=an.collective_counts,
     )
-
-
-def is_bandwidth_bound(fn, *args) -> bool:
-    """True when the memory term dominates the compute term for ``fn``.
-
-    Used by ``kernels/relational.py`` to gate the Pallas lowering of the
-    fused filter/project kernels: elementwise relational bodies carry zero
-    dot-flops, so they are bandwidth-bound whenever they move any bytes.
-    """
-    r = kernel_roofline(fn, *args)
-    return r.t_memory >= r.t_compute
 
 
 def train_model_flops(n_active_params: float, tokens: float) -> float:

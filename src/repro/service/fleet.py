@@ -289,6 +289,10 @@ class VerificationFleet:
     registry, and queue ends directly.  Sessions, caches, and the tier are
     constructed inside each worker, never inherited, so worker state is
     exactly what a fresh single process would build.
+
+    Workers are host verification processes.  An accelerator belongs to
+    one process at a time, so a config on the ``jax`` plane is refused:
+    N workers would each want the chip.
     """
 
     def __init__(
@@ -305,6 +309,13 @@ class VerificationFleet:
         if queue_size < 1:
             raise ValueError("queue_size must be positive")
         self.config = (config if config is not None else VeerConfig()).validate()
+        if self.config.plane == "jax":
+            raise ValueError(
+                "VerificationFleet workers are host verification processes; "
+                "the jax plane's device belongs to one process, so run "
+                "plane='jax' in a single VersionChainSession or "
+                "VerificationService instead"
+            )
         self.registry = registry
         self.keep_certificates = keep_certificates
         self.queue_size = queue_size

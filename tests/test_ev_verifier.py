@@ -136,6 +136,44 @@ def test_udf_window_jaxpr_ev():
     assert v is True
 
 
+def test_jaxpr_ev_traces_without_touching_a_device():
+    """Verifying a window must not initialize a backend: on a machine with
+    an accelerator that would take the chip in every verifying process."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    code = textwrap.dedent("""
+        from jax._src import xla_bridge
+        from repro.core import dag as D
+        from repro.core.ev.jaxpr_ev import _window_jaxpr
+        from repro.core.predicates import Pred
+
+        ops = [
+            D.Operator.make("s", D.SOURCE, schema=("a", "b")),
+            D.Operator.make("f", D.FILTER, pred=Pred.cmp("a", ">", 2)),
+            D.Operator.make("u", D.UDF, fn="double_all", out_schema=("a", "b")),
+            D.Operator.make("c", D.CLASSIFIER, col="b", out="cls"),
+            D.Operator.make("k", D.SINK, semantics=D.BAG),
+        ]
+        links = [D.Link("s", "f"), D.Link("f", "u"), D.Link("u", "c"),
+                 D.Link("c", "k")]
+        text = _window_jaxpr(D.DataflowDAG(ops, links), ["k"])
+        assert "lambda" in text, text
+        assert not xla_bridge._backends, list(xla_bridge._backends)
+        print("ok")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (src, env.get("PYTHONPATH")) if p
+    )
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
 def test_paper_example_mapping_matters():
     """Paper Fig 3: swap of Project and Aggregate under M1 vs M2."""
     P = chain(

@@ -35,6 +35,7 @@ from repro.api.config import VeerConfig
 from repro.engine.store import InMemoryMaterializationStore
 from repro.service import VerificationFleet
 from repro.service.chain import VersionChainSession
+from repro.service.pair_cache import PairVerdictCache
 from repro.workload.config import WorkloadConfig
 from repro.workload.generator import SessionGenerator
 from repro.workload.replay import REPLAY_EVS, canonical_results_bytes
@@ -112,6 +113,7 @@ def _sequential_reference(sessions):
         chain = VersionChainSession(
             config=CONFIG,
             materialization_store=InMemoryMaterializationStore(),
+            pair_cache=PairVerdictCache(),
         )
         reports = []
         for k, version in enumerate(s.versions):
@@ -164,6 +166,11 @@ def test_fleet_byte_identical_to_sequential(
     assert set(got) == set(reference)
     for sid in reference:
         assert got[sid] == reference[sid], f"divergence in session {sid}"
+
+
+def test_fleet_refuses_the_jax_plane():
+    with pytest.raises(ValueError, match="one process"):
+        VerificationFleet(2, config=CONFIG.replace(plane="jax"))
 
 
 def test_second_seed_with_larger_fleet_than_clients(tmp_path):

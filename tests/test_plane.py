@@ -78,6 +78,15 @@ def test_registry_lists_both_planes():
     assert get_plane("jax").name == "jax"
 
 
+def test_jax_plane_builds_where_jax_imports():
+    # jax imported above: a PlaneError here means the plane broke against
+    # the installed jax (as a removed x64 API once did), not a jax-less host
+    from repro.engine.plane.jax_plane import JaxPlane
+
+    assert JaxPlane().name == "jax"
+    assert get_plane("jax").name == "jax"
+
+
 def test_get_plane_unknown_raises():
     with pytest.raises(PlaneError, match="numpy"):
         get_plane("not-a-plane")
@@ -132,6 +141,106 @@ def test_exec_stats_accounting():
     res = ExecutionPlan(dag, sources, plane="jax").run()
     assert res.stats.plane == "jax"
     assert res.stats.ops_lowered >= 2  # filter + distinct at minimum
+
+
+def _filter_project_join_dag():
+    ops = [
+        Operator.make("l", D.SOURCE, schema=("k0", "k1", "k2", "x")),
+        Operator.make("r", D.SOURCE, schema=("k0", "k1", "k2", "y")),
+        Operator.make("f", D.FILTER, pred=Pred.cmp("x", "<=", 5)),
+        Operator.make("p", D.PROJECT, cols=(
+            ("k0", "k0"), ("k1", "k1"), ("k2", "k2"),
+            ("s", LinExpr.make({"x": 2, "k0": 1}, -1)),
+        )),
+        Operator.make("j", D.JOIN, on=(("k0", "k0"), ("k1", "k1"),
+                                       ("k2", "k2")), how="inner"),
+        Operator.make("di", D.DISTINCT),
+        Operator.make("sink", D.SINK, semantics=D.BAG),
+    ]
+    links = [Link("l", "f"), Link("f", "p"), Link("p", "j", 0),
+             Link("r", "j", 1), Link("j", "di"), Link("di", "sink")]
+    return DataflowDAG(ops, links)
+
+
+def _key_sources(n, key_range, seed=0):
+    """Three join keys: drawn from ``key_range`` values each, so 200 rows
+    of high-range keys combine into codes far past the dense threshold."""
+    rng = np.random.default_rng(seed)
+
+    def side(val):
+        cols = {f"k{i}": rng.integers(0, key_range, n).astype(np.float64)
+                for i in range(3)}
+        if key_range > n:  # let some rows match across the sides
+            for i in range(3):
+                cols[f"k{i}"][: n // 4] = np.arange(n // 4, dtype=np.float64)
+        cols[val] = rng.integers(0, 9, n).astype(np.float64)
+        return Table(cols, ["k0", "k1", "k2", val])
+
+    return {"l": side("x"), "r": side("y")}
+
+
+@pytest.mark.parametrize("key_range,join_on_device", [(4, False), (1 << 40, True)])
+def test_ops_on_device_counts_jitted_kernels(key_range, join_on_device):
+    """FILTER and PROJECT run jitted kernels; JOIN does only when its
+    combined key codes are sparse; DISTINCT is host-vectorized: lowered,
+    never on the device."""
+    dag = _filter_project_join_dag()
+    sources = _key_sources(200, key_range)
+    _assert_planes_identical(dag, sources)
+    stats = ExecutionPlan(dag, sources, plane="jax").run().stats
+    assert stats.ops_lowered == 4
+    assert stats.ops_on_device == 2 + join_on_device
+    assert ExecutionPlan(dag, sources, plane="numpy").run().stats.ops_on_device == 0
+
+
+def test_failed_exactness_probe_is_visible(monkeypatch):
+    """A kernel that diverges from the reference by one ulp fails the
+    probe: the plane says so (flag, first differing value, one warning),
+    runs FILTER and PROJECT on the host, and counts nothing on the device
+    — and the sinks stay byte-identical."""
+    import repro.kernels.relational as rel
+    from repro.engine import plane as plane_mod
+    from repro.engine.plane.jax_plane import JaxPlane
+
+    exact = rel.build_elementwise
+
+    def divergent(body):
+        fn = exact(body)
+
+        def call(*arrs):
+            out = fn(*arrs)
+            outs = out if isinstance(out, tuple) else (out,)
+            outs = tuple(np.nextafter(o, np.inf) if o.dtype.kind == "f" else o
+                         for o in outs)
+            return outs if isinstance(out, tuple) else outs[0]
+
+        return call
+
+    monkeypatch.setattr(rel, "build_elementwise", divergent)
+    plane = JaxPlane()
+    register_plane("jax_divergent", lambda: plane)
+    try:
+        dag = _filter_project_join_dag()
+        sources = _key_sources(200, 4)
+        with pytest.warns(RuntimeWarning, match="exactness probe failed"):
+            res = ExecutionPlan(dag, sources, plane="jax_divergent").run()
+        assert plane.exact is False
+        assert "row" in plane.probe_mismatch
+        assert "reference" in plane.probe_mismatch
+        assert res.stats.ops_on_device == 0
+        assert res.stats.ops_lowered == 2  # host-vectorized JOIN + DISTINCT
+        ref = execute(dag, sources, plane="numpy")
+        assert tables_identical(res.results["sink"], ref["sink"])
+    finally:
+        plane_mod._REGISTRY.pop("jax_divergent", None)
+        plane_mod._INSTANCES.pop("jax_divergent", None)
+
+
+def test_exactness_probe_passes_here():
+    plane = get_plane("jax")
+    dag = _filter_project_join_dag()
+    execute(dag, _key_sources(50, 4), plane="jax")
+    assert plane.exact is True and plane.probe_mismatch is None
 
 
 # ---------------------------------------------------------------------------
@@ -517,30 +626,25 @@ def test_combine_codes_overflow_fold():
 
 
 # ---------------------------------------------------------------------------
-# kernels: pallas interpret mode + jit bucket padding
+# kernels: jit bucket padding
 # ---------------------------------------------------------------------------
 
 
-def test_build_elementwise_interpret_matches_reference():
-    import jax.numpy as jnp
-
+def test_build_elementwise_bucket_padding_is_exact():
     from repro.kernels.relational import build_elementwise
 
     def body(x, y):
         return x + y, (x + y) <= 2.0
 
-    ref = build_elementwise(body, impl="reference")
-    interp = build_elementwise(body, impl="interpret")
+    fn = build_elementwise(body)
     for n in (0, 1, 7, 1024, 1025, 4097):
         rng = np.random.default_rng(n)
-        x = rng.integers(-3, 4, n).astype(np.float64)
-        y = rng.integers(-3, 4, n).astype(np.float64)
-        r_s, r_m = ref(x, y)
-        i_s, i_m = interp(x, y)
-        assert np.array_equal(r_s, i_s)
-        assert np.array_equal(r_m, i_m)
-        assert len(i_s) == n
-    assert jnp is not None
+        x = rng.integers(-3, 4, n).astype(np.float32)
+        y = rng.integers(-3, 4, n).astype(np.float32)
+        s, m = fn(x, y)
+        assert len(s) == len(m) == n
+        assert np.array_equal(s, x + y)
+        assert np.array_equal(m, (x + y) <= 2.0)
 
 
 def test_pow2_bucket():

@@ -36,13 +36,26 @@ def test_shape_bytes():
 def test_roofline_terms_and_bottleneck():
     r = Roofline(
         flops=1e14, hbm_bytes=1e12, collective_bytes=1e11,
-        model_flops=2e16, n_chips=256,
+        model_flops=2e16, n_chips=256, device_kind="TPU v5 lite",
     )
     assert r.t_compute == pytest.approx(1e14 / 197e12)
     assert r.t_memory == pytest.approx(1e12 / 819e9)
     assert r.t_collective == pytest.approx(1e11 / 50e9)
     assert r.bottleneck == "collective"
     assert 0 < r.roofline_fraction < 1
+
+
+def test_roofline_unknown_device_is_an_error():
+    from repro.launch.roofline import UnknownDevice
+
+    r = Roofline(
+        flops=1e14, hbm_bytes=1e12, collective_bytes=1e11,
+        model_flops=2e16, n_chips=1, device_kind="cpu",
+    )
+    assert r.flops == 1e14  # counts stand; no time or share does
+    for term in ("t_compute", "t_memory", "t_collective", "roofline_fraction"):
+        with pytest.raises(UnknownDevice, match="cpu"):
+            getattr(r, term)
 
 
 _SUBPROC = textwrap.dedent(
