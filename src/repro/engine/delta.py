@@ -60,6 +60,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import dag as D
 from repro.core.delta import AGG_SWAP, PROJECT_COLS, DeltaPlan
 from repro.engine.canon import column_codes, combine_codes, keyval, run_bounds
@@ -363,7 +364,8 @@ def _store_spine_output(store, stats, q_digests, q_id, state, t_p, elapsed):
     if key is None:
         raise DeltaUnsupported(f"no Q digest for {q_id}")
     table = _materialize(state, t_p)
-    wrote = store.put(key, table, elapsed)
+    with obs.span("veer.store.put", bytes=table.nbytes):
+        wrote = store.put(key, table, elapsed)
     stats.store_writes += wrote
     stats.store_dedup_skipped += not wrote
     return table

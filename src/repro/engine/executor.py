@@ -39,6 +39,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
+from repro import obs
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG
 from repro.engine.store import MaterializationStore, table_digest
@@ -277,7 +278,8 @@ class ExecutionPlan:
                 elapsed = time.perf_counter() - t0
                 stats.ops_executed += 1
                 if materialize and digests[op_id] is not None:
-                    wrote = store.put(digests[op_id], table, elapsed)
+                    with obs.span("veer.store.put", bytes=table.nbytes):
+                        wrote = store.put(digests[op_id], table, elapsed)
                     stats.store_writes += wrote
                     stats.store_dedup_skipped += not wrote
                 results[op_id] = table

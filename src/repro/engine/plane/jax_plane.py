@@ -58,6 +58,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import dag as D
 from repro.core.predicates import LinCmp, NonLinearAtom, Pred, StrEq
 from repro.engine.canon import column_codes, combine_codes, keyval
@@ -200,6 +201,13 @@ class JaxPlane(DataPlane):
             return False
 
     def execute_op(self, op: D.Operator, inputs: List[Table]) -> Table:
+        # every operator the executor and the delta engine run passes here:
+        # the one span per operator, named by its type
+        with obs.span(f"veer.exec.{op.op_type}", op=op.id,
+                      rows_in=sum(len(t) for t in inputs)):
+            return self._execute_op(op, inputs)
+
+    def _execute_op(self, op: D.Operator, inputs: List[Table]) -> Table:
         if not self.lowers(op, inputs):
             return self._ref.execute_op(op, inputs)
         t = op.op_type
@@ -495,14 +503,19 @@ class JaxPlane(DataPlane):
         # joint factorization: left and right key columns share one code
         # space per key position (dict-key equality incl. rounded collapse;
         # NaN keys get fresh codes so they never match — like the reference)
-        code_cols = []
-        for lc, rc in zip(l_on, r_on):
-            both = np.concatenate(
-                [np.asarray(left.cols[lc]), np.asarray(r.cols[rc])]
-            )
-            code_cols.append(column_codes(both, nan_distinct=True))
-        joint = combine_codes(code_cols)
-        lk, rk = joint[:nl], joint[nl:]
+        with obs.span("veer.plane.join.codes", nl=nl, nr=nr) as sp:
+            code_cols = []
+            for lc, rc in zip(l_on, r_on):
+                both = np.concatenate(
+                    [np.asarray(left.cols[lc]), np.asarray(r.cols[rc])]
+                )
+                code_cols.append(column_codes(both, nan_distinct=True))
+            joint = combine_codes(code_cols)
+            lk, rk = joint[:nl], joint[nl:]
+            max_code = int(joint.max()) if joint.size else 0
+            # sparse codes go to the device probe (see below)
+            device = max_code > max(1 << 22, 4 * (nl + nr))
+            sp.set_metadata(device=int(device))
 
         # probe: per-left-row windows [lo[i], hi[i]) into ``order`` — the
         # right indices stably sorted by key, so each window lists a key's
@@ -520,9 +533,10 @@ class JaxPlane(DataPlane):
         #     see combine_codes) so jit compiles once per power-of-two
         #     bucket, not once per row count.  Sentinels sit at the tail of
         #     the sorted keys and no real key's window can reach them.
-        max_code = int(joint.max()) if joint.size else 0
-        order = np.argsort(rk, kind="stable")
-        if max_code <= max(1 << 22, 4 * (nl + nr)):
+        with obs.span("veer.plane.join.argsort", nl=nl, nr=nr,
+                      device=int(device)):
+            order = np.argsort(rk, kind="stable")
+        if not device:
             counts_all = np.bincount(rk, minlength=max_code + 1)
             ends_all = np.cumsum(counts_all)
             lo = (ends_all - counts_all)[lk]
@@ -537,42 +551,48 @@ class JaxPlane(DataPlane):
             sr_p = np.full(pow2_bucket(nr), sentinel, dtype=np.int64)
             sr_p[:nr] = rk[order]
             self._dispatched()
-            with _x64():
-                lo, hi = self._probe()(jnp.asarray(lk_p), jnp.asarray(sr_p))
-            lo = np.asarray(lo)[:nl]
-            hi = np.asarray(hi)[:nl]
+            # dispatch, the wait behind other threads' programs, the
+            # program itself and both copies back
+            with obs.span("veer.plane.join.probe", nl=nl, nr=nr,
+                          bucket_l=len(lk_p), bucket_r=len(sr_p)):
+                with _x64():
+                    lo, hi = self._probe()(jnp.asarray(lk_p), jnp.asarray(sr_p))
+                lo = np.asarray(lo)[:nl]
+                hi = np.asarray(hi)[:nl]
 
         # expand the probe windows host-side, replicating the reference
         # output order exactly: left rows in order, each row's matches in
         # ascending right index (the stable argsort guarantees the window
         # order[lo[i]:hi[i]] is ascending), unmatched lefts appended after
-        counts = hi - lo
-        li = np.repeat(np.arange(nl, dtype=np.int64), counts)
-        starts_rep = np.repeat(lo, counts)
-        csum = np.cumsum(counts)
-        offs = np.arange(int(counts.sum()), dtype=np.int64) - np.repeat(
-            csum - counts, counts
-        )
-        ri = order[starts_rep + offs]
-        if how == "left_outer":
-            unmatched = np.flatnonzero(counts == 0)
-        else:
-            unmatched = np.array([], dtype=np.int64)
+        with obs.span("veer.plane.join.expand", nl=nl, nr=nr,
+                      device=int(device)):
+            counts = hi - lo
+            li = np.repeat(np.arange(nl, dtype=np.int64), counts)
+            starts_rep = np.repeat(lo, counts)
+            csum = np.cumsum(counts)
+            offs = np.arange(int(counts.sum()), dtype=np.int64) - np.repeat(
+                csum - counts, counts
+            )
+            ri = order[starts_rep + offs]
+            if how == "left_outer":
+                unmatched = np.flatnonzero(counts == 0)
+            else:
+                unmatched = np.array([], dtype=np.int64)
 
-        lt = left.take(np.concatenate([li, unmatched]).astype(int))
-        out_cols = {c: lt.cols[c] for c in left.order}
-        n_un = len(unmatched)
-        for c in r.order:
-            matched_vals = r.cols[c][ri] if len(ri) else r.cols[c][:0]
-            if n_un:
-                if matched_vals.dtype == object:
-                    pad = np.array([None] * n_un, dtype=object)
-                else:
-                    # same canonical padding rule as the reference plane:
-                    # np.nan pad, int columns upcast to float64
-                    pad = np.full(n_un, np.nan)
-                matched_vals = np.concatenate([matched_vals, pad])
-            out_cols[c] = matched_vals
+            lt = left.take(np.concatenate([li, unmatched]).astype(int))
+            out_cols = {c: lt.cols[c] for c in left.order}
+            n_un = len(unmatched)
+            for c in r.order:
+                matched_vals = r.cols[c][ri] if len(ri) else r.cols[c][:0]
+                if n_un:
+                    if matched_vals.dtype == object:
+                        pad = np.array([None] * n_un, dtype=object)
+                    else:
+                        # same canonical padding rule as the reference plane:
+                        # np.nan pad, int columns upcast to float64
+                        pad = np.full(n_un, np.nan)
+                    matched_vals = np.concatenate([matched_vals, pad])
+                out_cols[c] = matched_vals
         return Table(out_cols, left.order + r.order)
 
     # -- AGGREGATE: segment reduction over group codes ------------------------

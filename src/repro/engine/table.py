@@ -50,6 +50,12 @@ class Table:
     def __len__(self) -> int:
         return self.n
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the column arrays (an object column counts its
+        references, not the objects)."""
+        return sum(arr.nbytes for arr in self.cols.values())
+
     def col(self, name: str) -> np.ndarray:
         return self.cols[name]
 

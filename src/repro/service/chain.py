@@ -40,6 +40,7 @@ import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
+from repro import obs
 from repro.api.certificate import Certificate, certificate_from_evidence
 from repro.api.config import VeerConfig
 from repro.api.registry import EVRegistry
@@ -54,6 +55,9 @@ from repro.engine.executor import ExecStats, ExecutionPlan
 from repro.engine.store import MaterializationStore
 from repro.engine.table import Table
 from repro.service.pair_cache import PairVerdictCache
+
+
+_VERDICT_NAMES = {True: "eq", False: "neq", None: "unk"}
 
 
 @dataclass
@@ -372,19 +376,20 @@ class VersionChainSession:
         including for the **first** version, which gets a report (verdict
         ``None``, nothing to verify) instead of the verify-only ``None``.
         """
-        version.validate()
-        if sources is not None and self.store is None:
-            # checked before any session state moves: a rejected submit must
-            # leave the chain exactly where it was
-            raise ValueError(
-                "execute-with-reuse needs a session materialization_store"
-            )
-        prev, self._prev = self._prev, version
-        self.version_count += 1
-        plan: Optional[ExecutionPlan] = None
-        if sources is not None:
-            plan = ExecutionPlan(version, sources, plane=self.plane)
-        prev_plan, self._prev_plan = self._prev_plan, plan
+        with obs.span("veer.chain.plan", index=self.version_count):
+            version.validate()
+            if sources is not None and self.store is None:
+                # checked before any session state moves: a rejected submit
+                # must leave the chain exactly where it was
+                raise ValueError(
+                    "execute-with-reuse needs a session materialization_store"
+                )
+            prev, self._prev = self._prev, version
+            self.version_count += 1
+            plan: Optional[ExecutionPlan] = None
+            if sources is not None:
+                plan = ExecutionPlan(version, sources, plane=self.plane)
+            prev_plan, self._prev_plan = self._prev_plan, plan
 
         if prev is None:
             if plan is None:
@@ -401,15 +406,20 @@ class VersionChainSession:
             )
 
         t0 = time.perf_counter()
-        verdict, stats, certificate, reused = self._decide(prev, version, mapping)
+        with obs.span("veer.search.decide") as sp:
+            verdict, stats, certificate, reused = self._decide(
+                prev, version, mapping
+            )
+            sp.set_metadata(verdict=_VERDICT_NAMES[verdict], reused=int(reused))
         exec_stats = frontier = results = None
         if plan is not None:
             if self.exec_mode == "full":
                 res = plan.run(store=self.store, materialize=True)
             else:
-                frontier, seed_keys = self._frontier_seeds(
-                    prev, version, certificate, verdict, prev_plan, plan
-                )
+                with obs.span("veer.exec.frontier"):
+                    frontier, seed_keys = self._frontier_seeds(
+                        prev, version, certificate, verdict, prev_plan, plan
+                    )
                 res = None
                 if self.exec_mode == "delta" and frontier is not None:
                     res = self._try_delta(frontier, prev, prev_plan, plan)

@@ -45,10 +45,12 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.api.config import VeerConfig
 from repro.api.facade import VerificationResult, verify
 from repro.api.registry import EVRegistry
@@ -163,6 +165,8 @@ class _Job:
     ticket: int
     fn: Callable[[], object]
     future: Future
+    req: Optional[str] = None        # the spans' request id (repro.obs)
+    enqueued: float = 0.0            # perf_counter when it entered the queue
 
 
 def _fast_forward(state: _ClientState) -> None:
@@ -273,6 +277,7 @@ class VerificationService:
         # return while a submit it raced is still materializing its job.
         self._submitting = 0
         self._pending = 0
+        self._pairs_submitted = 0  # numbers one-shot pairs' request ids
         self._progress = threading.Condition(self._lock)
         # unsettled futures only; drain() folds settled ones into the
         # persistent aggregates below and drops them
@@ -338,6 +343,7 @@ class VerificationService:
                         version, mapping, sources=sources
                     ),
                     future=future,
+                    req=f"{client_id}:{ticket}",
                 )
                 self._enqueue(job, block, timeout)
             with self._lock:
@@ -363,6 +369,8 @@ class VerificationService:
             if self._closed:
                 raise ServiceClosed("service is closed")
             self._submitting += 1
+            pair_id = self._pairs_submitted
+            self._pairs_submitted += 1
         future: Future = Future()
         try:
             job = _Job(
@@ -370,6 +378,7 @@ class VerificationService:
                 ticket=0,
                 fn=lambda: self._verify_pair(P, Q, mapping),
                 future=future,
+                req=f"pair:{pair_id}",
             )
             self._enqueue(job, block, timeout)  # rejected jobs are never tracked
             with self._lock:
@@ -513,6 +522,7 @@ class VerificationService:
         # drain() observe a stale count (hang, or return before the job ran)
         with self._lock:
             self._pending += 1
+        job.enqueued = time.perf_counter()
         try:
             self._queue.put(job, block=block, timeout=timeout)
         except BaseException as e:
@@ -608,18 +618,24 @@ class VerificationService:
 
     def _execute(self, job: _Job) -> None:
         try:
-            # a future cancelled while queued/parked must be skipped, not
-            # run: set_result on a cancelled future raises InvalidStateError
-            # and would kill the worker thread.  For a chain job the ticket
-            # still advances (in _run), so the client's later jobs proceed —
-            # cancelling removes that version from the chain, cleanly.
-            if job.future.set_running_or_notify_cancel():
-                try:
-                    result = job.fn()
-                except BaseException as e:
-                    job.future.set_exception(e)
-                else:
-                    job.future.set_result(result)
+            with obs.request(job.req):
+                # the pickup: time in the queue, and parked behind the
+                # client's earlier jobs, up to this worker taking the job
+                with obs.span("veer.service.dequeue",
+                              queued_s=time.perf_counter() - job.enqueued):
+                    run = job.future.set_running_or_notify_cancel()
+                # a future cancelled while queued/parked must be skipped, not
+                # run: set_result on a cancelled future raises InvalidStateError
+                # and would kill the worker thread.  For a chain job the ticket
+                # still advances (in _run), so the client's later jobs proceed —
+                # cancelling removes that version from the chain, cleanly.
+                if run:
+                    try:
+                        result = job.fn()
+                    except BaseException as e:
+                        job.future.set_exception(e)
+                    else:
+                        job.future.set_result(result)
         finally:
             with self._lock:
                 self._pending -= 1
