@@ -37,7 +37,8 @@ Lowering map (see ``docs/DATA_PLANE.md`` for the rationale per row):
   PROJECT     fused two-program linear-expression kernel
   JOIN        joint unique-compression of key columns; dense codes probe
               a host bincount table, sparse codes a host stable argsort
-              plus a jitted searchsorted probe; host np.repeat expansion
+              plus a jitted two-level blocked search (fences, then one
+              gathered block per key); host np.repeat expansion
   AGGREGATE   group codes + stable argsort into contiguous segments;
               per-group reductions on contiguous float64 slices (same
               pairwise summation as the reference)
@@ -525,14 +526,17 @@ class JaxPlane(DataPlane):
         #     case since per-column codes come compressed): a bincount +
         #     exclusive-cumsum lookup table — O(1) gathers per left row, no
         #     per-query binary search;
-        #   * sparse codes: the jitted searchsorted probe of the left keys
-        #     into the sorted right keys.  The stable argsort stays on the
-        #     host: XLA:TPU takes minutes to compile a sort at these sizes,
-        #     and a binary search seconds.  Both operands are bucket-padded
-        #     by a sentinel above every possible code (codes stay < 2**61;
-        #     see combine_codes) so jit compiles once per power-of-two
-        #     bucket, not once per row count.  Sentinels sit at the tail of
-        #     the sorted keys and no real key's window can reach them.
+        #   * sparse codes: the jitted blocked search (``_join_probe_body``)
+        #     of the left keys into the sorted right keys: a compare-and-count
+        #     against the fence key of each block of B right keys, then the
+        #     same count over one gathered block per key.  The stable argsort
+        #     stays on the host: XLA:TPU takes minutes to compile a sort at
+        #     these sizes, and the probe seconds.  Both operands are
+        #     bucket-padded by a sentinel above every possible code (codes
+        #     stay < 2**61; see combine_codes) so jit compiles once per
+        #     power-of-two bucket, not once per row count; B and the chunk
+        #     of left keys follow from the buckets.  Sentinels sit at the
+        #     tail of the sorted keys and no real key's window can reach them.
         with obs.span("veer.plane.join.argsort", nl=nl, nr=nr,
                       device=int(device)):
             order = np.argsort(rk, kind="stable")
@@ -553,8 +557,9 @@ class JaxPlane(DataPlane):
             self._dispatched()
             # dispatch, the wait behind other threads' programs, the
             # program itself and both copies back
+            block, _, _ = probe_layout(len(lk_p), len(sr_p))
             with obs.span("veer.plane.join.probe", nl=nl, nr=nr,
-                          bucket_l=len(lk_p), bucket_r=len(sr_p)):
+                          bucket_l=len(lk_p), bucket_r=len(sr_p), block=block):
                 with _x64():
                     lo, hi = self._probe()(jnp.asarray(lk_p), jnp.asarray(sr_p))
                 lo = np.asarray(lo)[:nl]
@@ -772,16 +777,63 @@ class JaxPlane(DataPlane):
         return report
 
 
+#: elements of one chunk's ``(chunk, block)`` row gather in the join probe
+#: (32 MB of int64; on a TPU v5e 2^20 and 2^24 were both slower)
+_PROBE_CHUNK_ELEMS = 1 << 22
+
+
+def probe_layout(n_l: int, n_r: int) -> Tuple[int, int, int]:
+    """Static layout of the blocked join probe for ``n_l`` left keys into
+    ``n_r`` sorted right keys: the block width ``B = 2^ceil(log2(n_r)/2)``,
+    the block count ``M`` (the right keys padded up to ``M * B``) and the
+    number of left keys probed per chunk, which keeps the chunk's
+    ``(chunk, B)`` row gather at ``_PROBE_CHUNK_ELEMS`` elements."""
+    block = 1 << (max(n_r - 1, 0).bit_length() + 1) // 2
+    blocks = max(1, -(-n_r // block))
+    chunk = max(1, min(n_l, _PROBE_CHUNK_ELEMS // block))
+    return block, blocks, chunk
+
+
 def _join_probe_body(lk, sr):
     """Sorted-probe join kernel: each left key's window ``[lo, hi)`` in the
-    sorted right keys ``sr``.  Exact on int64 codes (a binary search over
-    total-ordered integers), so the host-side expansion reproduces the
-    reference bytes."""
-    import jax.numpy as jnp
+    sorted right keys ``sr``, equal to ``np.searchsorted(sr, lk, "left")``
+    and ``(..., "right")``.
 
-    lo = jnp.searchsorted(sr, lk, side="left")
-    hi = jnp.searchsorted(sr, lk, side="right")
-    return lo, hi
+    A two-level blocked search (``probe_layout``): the right keys, padded
+    with the largest int64, form ``M`` rows of ``B``, and each row's first
+    key is a fence.  A key ``x`` counts ``b`` fences below it (``<`` for
+    ``lo``, ``<=`` for ``hi``); every row before row ``b - 1`` lies wholly
+    below ``x`` and every row from ``b`` on wholly above it, so the window
+    bound is ``(b - 1) * B`` plus the same count over the one gathered row
+    ``b - 1`` (row 0 when ``b`` is 0, where that count is 0).  ``lo`` and
+    ``hi`` take their own fence counts, since a run of equal keys may span
+    rows.  Left keys go in chunks (``lax.map``) to bound the gathered rows.
+    Only int64 compares and counts, so the windows are exact and the
+    host-side expansion reproduces the reference bytes."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n_l, n_r = lk.shape[0], sr.shape[0]
+    block, m, chunk = probe_layout(n_l, n_r)
+    top = jnp.iinfo(jnp.int64).max
+    rows = jnp.pad(sr, (0, m * block - n_r), constant_values=top).reshape(m, block)
+    fences = rows[:, 0]
+    n_chunks = -(-n_l // chunk)
+    keys = jnp.pad(lk, (0, n_chunks * chunk - n_l)).reshape(n_chunks, chunk)
+
+    def bound(x, below):
+        b = jnp.sum(below(fences, x[:, None]), axis=1, dtype=jnp.int32)
+        j = jnp.maximum(b - 1, 0)
+        row = rows.at[j].get(mode="promise_in_bounds")
+        return j * block + jnp.sum(below(row, x[:, None]), axis=1, dtype=jnp.int32)
+
+    def probe(x):
+        # a key equal to the padding counts the padding too; every real
+        # right key lies at or below it, so the count caps at n_r
+        return bound(x, jnp.less), jnp.minimum(bound(x, jnp.less_equal), n_r)
+
+    lo, hi = lax.map(probe, keys)
+    return lo.reshape(-1)[:n_l], hi.reshape(-1)[:n_l]
 
 
 def use_compile_cache(root) -> str:

@@ -404,6 +404,93 @@ def test_sparse_code_join_uses_jitted_probe():
         })
 
 
+def test_sparse_code_join_over_several_probe_blocks():
+    """A sparse-code join whose right side fills many probe blocks, with
+    runs of equal keys both inside a block and longer than one."""
+    from repro.engine.plane.jax_plane import probe_layout
+    from repro.kernels.relational import pow2_bucket
+
+    rng = np.random.default_rng(14)
+    pool = rng.integers(0, 1 << 40, (400, 3)).astype(np.float64)
+    nl, nr, run = 3000, 1500, 150
+    block, n_blocks, _ = probe_layout(pow2_bucket(nl), pow2_bucket(nr))
+    assert n_blocks > 8 and run > 2 * block
+    lkeys = pool[rng.integers(0, 400, nl)]
+    rkeys = pool[rng.integers(200, 600, nr) % 400]
+    rkeys[:run] = pool[7]
+    lcols = {f"k{i}": lkeys[:, i] for i in range(3)}
+    rcols = {f"k{i}": rkeys[:, i] for i in range(3)}
+    lx = dict(lcols, x=np.arange(float(nl)))
+    ry = dict(rcols, y=np.arange(float(nr)))
+    on = tuple((f"k{i}", f"k{i}") for i in range(3))
+    sources = {"l": Table(lx, list(lx)), "r": Table(ry, list(ry))}
+    for how in ("inner", "left_outer"):
+        dag = _join_dag(how, schema_l=tuple(lx), schema_r=tuple(ry), on=on)
+        _assert_planes_identical(dag, sources)
+        assert ExecutionPlan(dag, sources, plane="jax").run().stats.ops_on_device == 1
+
+
+_CODE_SENTINEL = 1 << 62  # the plane pads both probe operands with it
+
+
+def _padded(keys, bucket):
+    out = np.full(bucket, _CODE_SENTINEL, dtype=np.int64)
+    out[: len(keys)] = keys
+    return out
+
+
+def _probe_case(name):
+    """``(lk, sr)`` for the blocked probe: left keys, sorted right keys."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "run_across_blocks":
+        # B = 16 for 200 right keys; 70 equal keys span five blocks
+        sr = np.sort(np.concatenate([rng.integers(0, 50, 130), np.full(70, 25)]))
+        return np.arange(-2, 53), sr
+    if name == "fences_and_ends":
+        sr = np.sort(rng.choice(1 << 40, 1000, replace=False))
+        fences = sr[:: 32]  # B = 32 for 1000 right keys
+        ends = [sr[0] - 1, sr[-1] + 1, np.iinfo(np.int64).min, np.iinfo(np.int64).max]
+        return np.concatenate([fences, fences - 1, fences + 1, ends]), sr
+    if name == "sentinel_tails":
+        sr = _padded(np.sort(rng.integers(0, 40, 100)), 128)
+        return _padded(rng.integers(-1, 42, 37), 64), sr
+    if name == "odd_length":
+        return rng.integers(-1, 300, 500), np.sort(rng.integers(0, 300, 777))
+    if name == "single_right_key":
+        return np.array([4, 5, 6, 5, _CODE_SENTINEL]), np.array([5])
+    if name == "near_2_61":
+        top = 1 << 61
+        sr = np.sort(rng.integers(top - 100, top, 300))
+        return np.concatenate([rng.integers(top - 103, top + 3, 200), [_CODE_SENTINEL]]), sr
+    assert name == "chunk_remainder"
+    # 4097 right keys: B = 128, a chunk of 2^22 / 128 = 32768 left keys
+    sr = np.sort(rng.integers(0, 5000, 4097))
+    return rng.integers(-1, 5001, 40000), sr
+
+
+@pytest.mark.parametrize("case", [
+    "run_across_blocks", "fences_and_ends", "sentinel_tails", "odd_length",
+    "single_right_key", "near_2_61", "chunk_remainder",
+])
+def test_blocked_join_probe_matches_searchsorted(case):
+    """The device probe's windows equal ``np.searchsorted``'s on both sides,
+    element for element."""
+    import jax.numpy as jnp
+
+    from repro.engine.plane.jax_plane import _join_probe_body, probe_layout
+
+    lk, sr = (np.asarray(a, dtype=np.int64) for a in _probe_case(case))
+    block, _, chunk = probe_layout(len(lk), len(sr))
+    if case == "run_across_blocks":
+        assert block == 16
+    if case == "chunk_remainder":
+        assert len(lk) % chunk != 0 and len(lk) > chunk
+    with jax.enable_x64(True):
+        lo, hi = jax.jit(_join_probe_body)(jnp.asarray(lk), jnp.asarray(sr))
+    np.testing.assert_array_equal(np.asarray(lo), np.searchsorted(sr, lk, side="left"))
+    np.testing.assert_array_equal(np.asarray(hi), np.searchsorted(sr, lk, side="right"))
+
+
 def test_single_group_aggregate():
     dag = _pipeline(
         Operator.make("ag", D.AGGREGATE, group_by=("a",),
