@@ -111,6 +111,15 @@ class Operator:
             object.__setattr__(self, "_signature", sig)
         return sig
 
+    def signature_repr(self) -> str:
+        """``repr(self.signature())``, memoized like the signature: window
+        fingerprints serialize every operator of every window."""
+        text = self.__dict__.get("_signature_repr")
+        if text is None:
+            text = repr(self.signature())
+            object.__setattr__(self, "_signature_repr", text)
+        return text
+
     def arity(self) -> int:
         if self.op_type == SOURCE:
             return 0

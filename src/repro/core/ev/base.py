@@ -15,9 +15,11 @@ can decide (Def 4.2/4.3) — plus two capability bits the verifier relies on:
 from __future__ import annotations
 
 import hashlib
+import time
 from dataclasses import dataclass, field
-from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
+from typing import Dict, FrozenSet, Iterator, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core.dag import BAG, ORDERED, SET, SOURCE, DataflowDAG
 
 
@@ -68,38 +70,86 @@ class QueryPair:
         cached = self.__dict__.get("_fingerprint")
         if cached is not None:
             return cached
-        pairs = []
-        for ps, qs in self.sink_pairs:
-            tokens: Dict[str, int] = {}
-            local: List[Tuple] = []
-            _canon_cone(self.P, ps, tokens, {}, local)
-            local.append(("side",))
-            _canon_cone(self.Q, qs, tokens, {}, local)
-            pairs.append((repr(local), ps, qs))
-        pairs.sort(key=lambda x: x[0])
-        tokens = {}
+        pairs = list(self.sink_pairs)
+        if len(pairs) > 1:
+            # each pair's local serialization is produced only as far as
+            # the sort's comparisons need it
+            local = {pq: _Items(self._local_items(*pq)) for pq in pairs}
+            pairs.sort(key=lambda pq: local[pq])
+        tokens: Dict[str, int] = {}
         ix_p: Dict[str, int] = {}
         ix_q: Dict[str, int] = {}
-        stream: List[Tuple] = []
-        for _, ps, qs in pairs:
-            stream.append(("sink",))
-            _canon_cone(self.P, ps, tokens, ix_p, stream)
-            stream.append(("side",))
-            _canon_cone(self.Q, qs, tokens, ix_q, stream)
-        blob = repr((self.semantics, self.at_version_sink, stream))
+        stream: List[str] = []
+        for ps, qs in pairs:
+            stream.append(_SINK)
+            stream.extend(_cone_items(self.P, ps, tokens, ix_p))
+            stream.append(_SIDE)
+            stream.extend(_cone_items(self.Q, qs, tokens, ix_q))
+        blob = (f"({self.semantics!r}, {self.at_version_sink!r}, "
+                f"{_repr_list(stream)})")
         digest = hashlib.sha256(blob.encode()).hexdigest()[:32]
         object.__setattr__(self, "_fingerprint", digest)  # frozen-safe memo
         return digest
 
+    def _local_items(self, ps: str, qs: str) -> Iterator[str]:
+        """One sink pair's id-free serialization on its own: fresh source
+        tokens and operator indices."""
+        tokens: Dict[str, int] = {}
+        yield from _cone_items(self.P, ps, tokens, {})
+        yield _SIDE
+        yield from _cone_items(self.Q, qs, tokens, {})
 
-def _canon_cone(
+
+# the stream's items, each written as ``repr`` writes its tuple: the stream
+# is a list of them, and ``_repr_list`` joins them as ``repr`` of that list
+_SIDE = repr(("side",))
+_SINK = repr(("sink",))
+_END = repr(("end",))
+
+
+def _repr_list(items: List[str]) -> str:
+    return "[" + ", ".join(items) + "]"
+
+
+class _Items:
+    """A sink pair's local serialization, drawn from its generator as far
+    as comparisons need, ordered as ``_repr_list`` of the whole sequence
+    would order: item by item (one item's repr is never a proper prefix
+    of another's), and where one sequence is a prefix of the other the
+    longer first (``", "`` sorts before ``"]"``)."""
+
+    __slots__ = ("items", "rest")
+
+    def __init__(self, items: Iterator[str]):
+        self.items: List[str] = []
+        self.rest = items
+
+    def _at(self, i: int) -> Optional[str]:
+        while len(self.items) <= i:
+            nxt = next(self.rest, None)
+            if nxt is None:
+                return None
+            self.items.append(nxt)
+        return self.items[i]
+
+    def __lt__(self, other: "_Items") -> bool:
+        i = 0
+        while True:
+            a, b = self._at(i), other._at(i)
+            if a is None or b is None:
+                return a is not None and b is None
+            if a != b:
+                return a < b
+            i += 1
+
+
+def _cone_items(
     dag: DataflowDAG,
     root: str,
     source_tokens: Dict[str, int],
     node_ix: Dict[str, int],
-    out: List[Tuple],
-) -> None:
-    """Append an id-free serialization of the cone feeding ``root`` to ``out``.
+) -> Iterator[str]:
+    """An id-free serialization of the cone feeding ``root``, item by item.
 
     The stream is flat (balanced ``begin``/``end`` markers instead of nested
     tuples) and the traversal iterative, so arbitrarily deep pipelines neither
@@ -108,24 +158,26 @@ def _canon_cone(
     serialize as ``("ref", index)``.  Sources serialize as
     ``("src", token, signature)`` where the token dict is shared between the
     P and Q sides of a pair (ids coincide there by construction), making the
-    cross-side source correspondence part of the canonical form.
+    cross-side source correspondence part of the canonical form.  Each item
+    is the ``repr`` of that tuple, with the operator's signature rendered
+    once per operator (``Operator.signature_repr``).
     """
     stack: List[Tuple[str, str]] = [("visit", root)]
     while stack:
         action, op_id = stack.pop()
         if action == "end":
             node_ix[op_id] = len(node_ix)
-            out.append(("end",))
+            yield _END
             continue
         op = dag.ops[op_id]
         if op.op_type == SOURCE:
             tok = source_tokens.setdefault(op_id, len(source_tokens))
-            out.append(("src", tok, op.signature()))
+            yield f"('src', {tok}, {op.signature_repr()})"
             continue
         if op_id in node_ix:
-            out.append(("ref", node_ix[op_id]))
+            yield f"('ref', {node_ix[op_id]})"
             continue
-        out.append(("begin", op.signature()))
+        yield f"('begin', {op.signature_repr()})"
         stack.append(("end", op_id))
         for l in reversed(dag.in_links.get(op_id, ())):
             stack.append(("visit", l.src))
@@ -164,6 +216,20 @@ class BaseEV:
 
     def __repr__(self) -> str:
         return f"EV({self.name})"
+
+
+VERDICT_NAMES = {True: "eq", False: "neq", None: "unk"}
+
+
+def timed_check(ev: BaseEV, qp: QueryPair) -> Tuple[Optional[bool], float]:
+    """``ev.check(qp)`` and its wall seconds, inside a ``veer.ev.check``
+    span: ``ev`` the EV's name, ``ops`` the window's operators on both
+    sides (symbolic inputs included), ``verdict`` eq/neq/unk."""
+    t0 = time.perf_counter()
+    with obs.span("veer.ev.check", ev=ev.name, ops=len(qp.P.ops) + len(qp.Q.ops)) as sp:
+        verdict = ev.check(qp)
+        sp.set_metadata(verdict=VERDICT_NAMES[verdict])
+    return verdict, time.perf_counter() - t0
 
 
 class EVCallCounter:

@@ -58,12 +58,11 @@ import pathlib
 import stat
 import tempfile
 import threading
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, Iterable, Optional, Tuple
 
-from repro.core.ev.base import BaseEV, QueryPair
+from repro.core.ev.base import BaseEV, QueryPair, timed_check
 
 # bump when an EV's decision procedure changes incompatibly: old persisted
 # verdicts are discarded instead of replayed
@@ -353,9 +352,7 @@ class CachedEV:
                 self.hits += 1
                 self.time_saved += entry.elapsed
             return entry.verdict, True, 0.0, entry.elapsed
-        t0 = time.perf_counter()
-        verdict = self.ev.check(qp)
-        elapsed = time.perf_counter() - t0
+        verdict, elapsed = timed_check(self.ev, qp)
         with self._lock:
             self.misses += 1
         self.cache.put(self.ev.name, fp, verdict, elapsed)

@@ -43,6 +43,7 @@ from typing import Dict, FrozenSet, List, Mapping, Optional, Sequence, Tuple, Un
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG
 from repro.core.predicates import LinCmp, LinExpr, Pred
+from repro.core.ev import memo as ev_memo
 from repro.core.ev import solver
 
 
@@ -139,19 +140,32 @@ SPINE_TYPES = frozenset({D.AGGREGATE, D.UNION})  # + left_outer joins
 
 
 def normalize(dag: DataflowDAG, sink_id: str, *, allow_union: bool = True) -> Block:
-    """Normal form of the query rooted at ``sink_id``."""
-    memo: Dict[str, Block] = {}
+    """Normal form of the query rooted at ``sink_id``.
 
-    def go(op_id: str) -> Block:
-        if op_id in memo:
-            return memo[op_id]
+    Within a pair's ``memo.scope`` each operator's normal form is looked up
+    by its structure (``memo.PairMemo.node_key``): the windows of one pair,
+    and the sinks of one window, share their upstream cones."""
+    pair = ev_memo.active()
+    done: Dict[str, Tuple[int, Block]] = {}
+
+    def go(op_id: str) -> Tuple[int, Block]:
+        if op_id in done:
+            return done[op_id]
         op = dag.ops[op_id]
-        ins = [l.src for l in dag.in_links.get(op_id, [])]
-        out = _normalize_op(dag, op, [go(i) for i in ins], allow_union=allow_union)
-        memo[op_id] = out
+        ins = [go(l.src) for l in dag.in_links.get(op_id, [])]
+        if pair is None:
+            out = (0, _normalize_op(dag, op, [b for _, b in ins], allow_union=allow_union))
+        else:
+            key = pair.node_key(op, tuple(k for k, _ in ins))
+            block = pair.blocks.get((key, allow_union))
+            if block is None:
+                block = _normalize_op(dag, op, [b for _, b in ins], allow_union=allow_union)
+                pair.blocks[(key, allow_union)] = block
+            out = (key, block)
+        done[op_id] = out
         return out
 
-    return go(sink_id)
+    return go(sink_id)[1]
 
 
 def _normalize_op(
@@ -422,9 +436,30 @@ def _multiset_match(xs: List, ys: List, eq) -> bool:
 def blocks_equivalent(
     A: Block, B: Block, budget: Optional[_Budget] = None, memo: Optional[dict] = None
 ) -> bool:
-    """Bag-equivalence of SPJ blocks (complete for linear SPJ)."""
-    budget = budget or _Budget()
-    memo = memo if memo is not None else {}
+    """Bag-equivalence of SPJ blocks (complete for linear SPJ).
+
+    Within a pair's ``memo.scope`` an outermost call is looked up by the
+    two blocks (``memo.PairMemo.equivalent``): normal forms come from the
+    pair's memo, so windows that share a sink's cones pass the same
+    blocks here."""
+    if budget is None:
+        pair = ev_memo.active()
+        if pair is not None:
+            kept = pair.equivalent.get((id(A), id(B)))
+            if kept is None:
+                try:
+                    out = _blocks_equivalent(A, B, _Budget(), {})
+                except UnsupportedOp as e:
+                    out = e
+                # the blocks are kept too, so their ids name them
+                kept = pair.equivalent[(id(A), id(B))] = (A, B, out)
+            if isinstance(kept[2], UnsupportedOp):
+                raise UnsupportedOp(*kept[2].args)
+            return kept[2]
+    return _blocks_equivalent(A, B, budget or _Budget(), memo if memo is not None else {})
+
+
+def _blocks_equivalent(A: Block, B: Block, budget: _Budget, memo: dict) -> bool:
     if A.schema != B.schema:
         return False
     try:

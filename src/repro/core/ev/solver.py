@@ -11,7 +11,8 @@ installed offline, so we implement the linear-rational fragment ourselves:
 Fourier-Motzkin over rationals is sound and complete for conjunctions of
 (strict/non-strict) linear inequalities; equalities are substituted out via
 Gaussian pivoting first, which keeps the blow-up tame at workflow-predicate
-sizes (a handful of columns).  String-equality atoms are decided separately
+sizes (a handful of columns: a join's key equalities leave no rows), and a
+disequality splits the system into its two strict sides.  String-equality atoms are decided separately
 (conflicting literals / contradicting negations) — sound because string and
 numeric domains are disjoint in our operator model.
 """
@@ -21,6 +22,7 @@ from __future__ import annotations
 from fractions import Fraction
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from repro.core.ev import memo
 from repro.core.predicates import (
     Atom,
     LinCmp,
@@ -37,15 +39,21 @@ class UnsupportedAtomError(Exception):
 
 # Internal constraint: (coeffs dict, const, strict) meaning  expr <= 0 / expr < 0
 _Constraint = Tuple[Dict[str, Fraction], Fraction, bool]
+# Internal equality: (coeffs dict, const) meaning  expr == 0
+_Equality = Tuple[Dict[str, Fraction], Fraction]
 
 
-def _to_constraints(atoms: Iterable[Atom]) -> Optional[List[_Constraint]]:
-    """Lower atoms to <=/< constraints. Returns None if trivially unsat
-    (string conflicts). Raises UnsupportedAtomError on non-linear atoms."""
+def _lower(
+    atoms: Iterable[Atom],
+) -> Optional[Tuple[List[_Constraint], List[_Equality], List[LinExpr]]]:
+    """Split atoms into ``<=``/``<`` rows, equalities and disequalities
+    (``expr != 0``).  Returns None if trivially unsat (string conflicts).
+    Raises UnsupportedAtomError on non-linear atoms."""
     cons: List[_Constraint] = []
+    eqs: List[_Equality] = []
+    disequalities: List[LinExpr] = []
     str_eq: Dict[str, str] = {}
     str_ne: Dict[str, set] = {}
-    disequalities: List[LinExpr] = []
 
     for a in atoms:
         if isinstance(a, NonLinearAtom):
@@ -66,8 +74,7 @@ def _to_constraints(atoms: Iterable[Atom]) -> Optional[List[_Constraint]]:
         elif a.op == "<":
             cons.append((d, c, True))
         elif a.op == "==":
-            cons.append((dict(d), c, False))
-            cons.append(({k: -v for k, v in d.items()}, -c, False))
+            eqs.append((d, c))
         elif a.op == "!=":
             disequalities.append(a.expr)
         else:
@@ -76,30 +83,62 @@ def _to_constraints(atoms: Iterable[Atom]) -> Optional[List[_Constraint]]:
     for col, vals in str_ne.items():
         if col in str_eq and str_eq[col] in vals:
             return None
-
-    # Disequalities over a dense order: expr != 0 cuts out a measure-zero set.
-    # The conjunction is satisfiable iff the <=/< system has a solution not on
-    # any of the hyperplanes. We handle them by case split: expr<0 OR expr>0.
-    if disequalities:
-        e = disequalities[0]
-        rest = disequalities[1:]
-        for branch in (LinCmp(e, "<"), LinCmp(e.scale(-1), "<")):
-            sub = _to_constraints([branch] + [LinCmp(x, "!=") for x in rest])
-            if sub is None:
-                continue
-            merged = cons + sub
-            if _fm_satisfiable(merged):
-                # signal satisfiable by returning a witness-compatible system
-                return merged
-        return None
-
-    return cons
+    return cons, eqs, disequalities
 
 
-def _fm_satisfiable(cons: List[_Constraint]) -> bool:
-    """Fourier-Motzkin elimination. True iff the system has a rational solution."""
+def _split_disequalities(
+    cons: List[_Constraint], eqs: List[_Equality], disequalities: List[LinExpr]
+) -> bool:
+    """Disequalities over a dense order: ``expr != 0`` cuts out a hyperplane,
+    so it holds on ``expr < 0`` or on ``expr > 0``; the system is
+    satisfiable iff one choice of sides for all of them is."""
+    if not disequalities:
+        return _fm_satisfiable(cons, eqs)
+    e, rest = disequalities[0], disequalities[1:]
+    d = dict(e.coeffs)
+    below = (d, e.const, True)
+    above = ({k: -v for k, v in d.items()}, -e.const, True)
+    return any(_split_disequalities(cons + [side], eqs, rest) for side in (below, above))
+
+
+def _substitute(
+    d: Dict[str, Fraction], c: Fraction, x: str, expr: Dict[str, Fraction],
+    const: Fraction,
+) -> Tuple[Dict[str, Fraction], Fraction]:
+    """``d·vars + c`` with ``x`` replaced by ``expr·vars + const``."""
+    k = d.get(x)
+    if not k:
+        return d, c
+    out = {y: v for y, v in d.items() if y != x}
+    for y, v in expr.items():
+        out[y] = out.get(y, Fraction(0)) + k * v
+    return {y: v for y, v in out.items() if v != 0}, c + k * const
+
+
+def _fm_satisfiable(cons: List[_Constraint], eqs: Sequence[_Equality] = ()) -> bool:
+    """Fourier-Motzkin elimination. True iff the system has a rational solution.
+
+    Equalities go first, by substitution: each solves for its smallest
+    variable, which then leaves every other row (an equality left with no
+    variable is checked as a constant); Fourier-Motzkin then eliminates the
+    variables of the inequalities alone.  An equality as two opposite
+    inequality rows would give the same answer after many more rows."""
+    pending = [(dict(d), c) for d, c in eqs]
     cons = [(dict(d), c, s) for d, c, s in cons]
-    # collect variables
+    while pending:
+        d, c = pending.pop()
+        d = {k: v for k, v in d.items() if v != 0}
+        if not d:
+            if c != 0:
+                return False
+            continue
+        x = min(d)
+        a = d.pop(x)
+        # a·x + d·vars + c == 0  ⇒  x = (-d/a)·vars + (-c/a)
+        expr = {y: -v / a for y, v in d.items()}
+        const = -c / a
+        pending = [_substitute(dd, cc, x, expr, const) for dd, cc in pending]
+        cons = [(*_substitute(dd, cc, x, expr, const), s) for dd, cc, s in cons]
     while True:
         vars_ = sorted({v for d, _, _ in cons for v in d if d[v] != 0})
         if not vars_:
@@ -164,11 +203,25 @@ def _fm_satisfiable(cons: List[_Constraint]) -> bool:
 
 
 def satisfiable(atoms: Sequence[Atom]) -> bool:
-    """Conjunction satisfiability over Q (+ disjoint string domain)."""
-    cons = _to_constraints(atoms)
-    if cons is None:
+    """Conjunction satisfiability over Q (+ disjoint string domain).
+
+    Within a pair's ``memo.scope`` a conjunction already decided for the
+    pair is looked up, not decided again (``memo.PairMemo.sat``)."""
+    pair = memo.active()
+    if pair is None:
+        return _decide(atoms)
+    key = frozenset(atoms)
+    out = pair.sat.get(key)
+    if out is None:
+        out = pair.sat[key] = _decide(atoms)
+    return out
+
+
+def _decide(atoms: Sequence[Atom]) -> bool:
+    lowered = _lower(atoms)
+    if lowered is None:
         return False
-    return _fm_satisfiable(cons)
+    return _split_disequalities(*lowered)
 
 
 def implies(premise: Sequence[Atom], conclusion: Atom) -> bool:

@@ -35,7 +35,8 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, FrozenSet, List, Optional, Sequence, Set, Tuple
 
-from repro.core.ev.base import BaseEV, QueryPair
+from repro.core.ev import memo
+from repro.core.ev.base import BaseEV, QueryPair, timed_check
 from repro.core.ev.cache import CachedEV, VerdictCache
 from repro.core.ranking import decomposition_score
 from repro.core.window import Change, VersionPair, identical_under_mapping
@@ -239,10 +240,9 @@ class BaseSearchContext:
                     out.ev_calls += 1
                     out.ev_time += dt
             else:
-                t0 = time.perf_counter()
-                r = ev.check(qp)
+                r, dt = timed_check(ev, qp)
                 out.ev_calls += 1
-                out.ev_time += time.perf_counter() - t0
+                out.ev_time += dt
             if r is True:
                 out.verdict = TRUE
                 out.provenance = ("ev", ev.name)
@@ -291,7 +291,9 @@ class BaseSearchContext:
         targets = [w for w in order if w not in self._verdict]
         if len(targets) < 2:
             return  # nothing to overlap
-        futures = [(w, pool.submit(self._compute_outcome, w)) for w in targets]
+        pair_memo = memo.active()
+        futures = [(w, pool.submit(memo.run_in, pair_memo, self._compute_outcome, w))
+                   for w in targets]
         for w, fut in futures:
             self._commit_outcome(w, fut.result())
 

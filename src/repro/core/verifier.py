@@ -34,6 +34,7 @@ from typing import Dict, FrozenSet, List, Optional, Sequence, Set, Tuple
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG
 from repro.core.edits import EditMapping, enumerate_mappings, identity_mapping
+from repro.core.ev import memo
 from repro.core.ev.base import BaseEV, QueryPair
 from repro.core.ev.cache import VerdictCache, wrap_evs
 from repro.core.ranking import decomposition_score_from_sizes, segment_score
@@ -121,6 +122,10 @@ class VeerStats:
     windows_verified: int = 0
     ev_calls: int = 0
     ev_time: float = 0.0
+    # satisfiability problems the EVs decided for this pair; a conjunction
+    # decided before in the same pair is looked up, not counted again
+    # (``repro.core.ev.memo``)
+    sat_calls: int = 0
     explore_time: float = 0.0
     total_time: float = 0.0
     segments: int = 0
@@ -303,19 +308,22 @@ class Veer:
         )
         verdict: Optional[bool] = UNKNOWN
         evidence: Optional[VerificationEvidence] = None
-        for m in mappings:
-            stats.mappings_tried += 1
-            try:
-                pair = VersionPair(P, Q, m, semantics)
-            except (D.DAGError, ValueError):
-                continue
-            coll = _EvidenceCollector()
-            coll.pair = pair
-            verdict = self._verify_pair(pair, stats, coll)
-            if verdict is not UNKNOWN:
-                if collect:
-                    evidence = _assemble_evidence(verdict, coll)
-                break
+        pair_memo = memo.PairMemo()
+        with memo.scope(pair_memo):
+            for m in mappings:
+                stats.mappings_tried += 1
+                try:
+                    pair = VersionPair(P, Q, m, semantics)
+                except (D.DAGError, ValueError):
+                    continue
+                coll = _EvidenceCollector()
+                coll.pair = pair
+                verdict = self._verify_pair(pair, stats, coll)
+                if verdict is not UNKNOWN:
+                    if collect:
+                        evidence = _assemble_evidence(verdict, coll)
+                    break
+        stats.sat_calls = len(pair_memo.sat)
         stats.total_time = time.perf_counter() - t0
         stats.verdict = verdict
         return verdict, stats, evidence

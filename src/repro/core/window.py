@@ -100,6 +100,8 @@ class VersionPair:
         Q.validate()
         self.P, self.Q, self.mapping = P, Q, mapping
         self.semantics = semantics
+        # the windows' symbolic sources, by (side, id)
+        self._symbolic: Dict[Tuple[str, str], Operator] = {}
         fwd = mapping.forward
         bwd = mapping.backward
 
@@ -572,16 +574,24 @@ class VersionPair:
                 canonical = l.src if side == "p" else bwd[l.src]
                 sym_id = f"__in__{canonical}"
                 if sym_id not in extra_ops:
-                    extra_ops[sym_id] = Operator.make(
-                        sym_id, D.SOURCE, schema=tuple(schemas[l.src])
-                    )
+                    # one object per input and side for all windows, so its
+                    # memoized signature serves every window's fingerprint
+                    sym = self._symbolic.get((side, sym_id))
+                    if sym is None:
+                        sym = self._symbolic[(side, sym_id)] = Operator.make(
+                            sym_id, D.SOURCE, schema=tuple(schemas[l.src])
+                        )
+                    extra_ops[sym_id] = sym
                 links.append(Link(sym_id, l.dst, l.dst_port))
+        # no ``validate()``: the pair validated ``P`` and ``Q``, and a window
+        # keeps every input port of its operators (a producer outside it
+        # becomes a symbolic source), so the sub-DAG is acyclic with the
+        # version's arities and ports; the constructor still refuses a
+        # duplicate id or a dangling link
         try:
-            sub = DataflowDAG(ops + list(extra_ops.values()), links)
-            sub.validate()
+            return DataflowDAG(ops + list(extra_ops.values()), links)
         except D.DAGError:
             return None
-        return sub
 
 
 _UNSET = object()  # WindowTable lazy-slot sentinel (None is a valid value)

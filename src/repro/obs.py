@@ -15,10 +15,12 @@ carries ``req=req_id``, so all spans of one job share an identifier and
 one request can be followed across the job's spans.
 
 Rule for the names (every one starts with ``veer.``): on one thread at
-most one ``veer.`` span is open at a time, except the join phases
-(``veer.plane.join.*``) inside ``veer.exec.Join``.  A gap of the device
-is then named by the one span that covers it, and a job's context
-travels as ``req``, never as an enclosing span.
+most one ``veer.`` span is open at a time, except two kinds of phases
+inside their operation: the join phases (``veer.plane.join.*``) inside
+``veer.exec.Join``, and the EV calls (``veer.ev.check``) inside
+``veer.search.decide``.  A gap of the device is then named by the span
+that covers it, and a job's context travels as ``req``, never as an
+enclosing span.
 
 jax is never imported here: a trace can only be recording in a process
 that has already loaded ``jax.profiler``, so where it is absent a span is

@@ -47,7 +47,7 @@ from repro.api.registry import EVRegistry
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG
 from repro.core.edits import EditMapping
-from repro.core.ev.base import BaseEV
+from repro.core.ev.base import VERDICT_NAMES, BaseEV
 from repro.core.ev.cache import VerdictCache
 from repro.core.frontier import FrontierError, ReuseFrontier, compute_reuse_frontier
 from repro.core.verifier import Veer, VeerStats, make_veer_plus
@@ -55,9 +55,6 @@ from repro.engine.executor import ExecStats, ExecutionPlan
 from repro.engine.store import MaterializationStore
 from repro.engine.table import Table
 from repro.service.pair_cache import PairVerdictCache
-
-
-_VERDICT_NAMES = {True: "eq", False: "neq", None: "unk"}
 
 
 @dataclass
@@ -410,7 +407,9 @@ class VersionChainSession:
             verdict, stats, certificate, reused = self._decide(
                 prev, version, mapping
             )
-            sp.set_metadata(verdict=_VERDICT_NAMES[verdict], reused=int(reused))
+            sp.set_metadata(verdict=VERDICT_NAMES[verdict], reused=int(reused),
+                            decompositions=stats.decompositions_explored,
+                            ev_calls=stats.ev_calls, sat_calls=stats.sat_calls)
         exec_stats = frontier = results = None
         if plan is not None:
             if self.exec_mode == "full":

@@ -1,8 +1,9 @@
 """The program's spans (``repro.obs``): free when no trace records, and,
 under ``jax.profiler.start_trace``, one span per operator, no two
 ``veer.`` spans open at once on a thread (but the join phases inside
-``veer.exec.Join``), one request id per job on every span of that job,
-and the queue wait on every pickup."""
+``veer.exec.Join`` and the EV calls inside ``veer.search.decide``), one
+request id per job on every span of that job, and the queue wait on
+every pickup."""
 
 import glob
 import sys
@@ -154,17 +155,19 @@ def test_no_two_spans_open_on_a_thread_but_the_join_phases(traced):
     by_thread = defaultdict(list)
     for thread, name, start, end, _ in spans:
         by_thread[thread].append((start, end, name))
+    inside = {"veer.exec.Join": "veer.plane.join.", "veer.search.decide": "veer.ev.check"}
     for items in by_thread.values():
         items.sort()
-        open_join = None
+        outer = None  # (start, end, prefix of the phases it may hold)
         last_end = -1
         for start, end, name in items:
-            if name.startswith("veer.plane.join."):
-                assert open_join is not None and open_join[0] <= start and end <= open_join[1]
+            if outer is not None and name.startswith(outer[2]):
+                assert outer[0] <= start and end <= outer[1], (name, start, outer)
                 continue
+            assert not name.startswith(tuple(inside.values())), name
             assert start >= last_end, (name, start, last_end)
             last_end = end
-            open_join = (start, end) if name == "veer.exec.Join" else None
+            outer = (start, end, inside[name]) if name in inside else None
 
 
 def test_every_span_of_a_job_carries_its_request_id(traced):
@@ -192,3 +195,16 @@ def test_every_dequeue_carries_its_queue_wait(traced):
     assert all(isinstance(s["queued_s"], float) and 0 <= s["queued_s"] < 120 for s in dequeues)
     probes = [stats for _, name, *_, stats in spans if name == "veer.plane.join.probe"]
     assert probes and all(s["bucket_l"] >= s["nl"] and s["bucket_r"] >= s["nr"] for s in probes)
+
+
+def test_decide_spans_count_their_search_and_each_ev_call_has_a_span(traced):
+    spans, _, _ = traced
+    decide = [stats for _, name, *_, stats in spans if name == "veer.search.decide"]
+    for s in decide:
+        assert all(s[k] >= 0 for k in ("decompositions", "ev_calls", "sat_calls")), s
+    checks = [stats for _, name, *_, stats in spans if name == "veer.ev.check"]
+    assert checks and len(checks) == sum(s["ev_calls"] for s in decide)
+    assert sum(s["sat_calls"] for s in decide) > 0
+    for s in checks:
+        assert s["ev"] in ("equitas", "spes", "udp", "jaxpr") and s["ops"] >= 2, s
+        assert s["verdict"] in ("eq", "neq", "unk"), s

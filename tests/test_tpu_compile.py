@@ -22,8 +22,9 @@ import pytest
 ROWS = 1 << 20
 KERNELS = ("filter_mul", "filter_mask", "project_sum", "join_probe")
 MAX_COMPILE_S = 60.0
-#: (left, right) buckets of the join probe beyond ``ROWS`` x ``ROWS``
-PROBE_BUCKETS = ((1 << 21, 1 << 18),)
+#: (left, right) buckets of the join probe beyond ``ROWS`` x ``ROWS``: the
+#: returns joins of TPC-DS Q40 and Q50 at SF1
+PROBE_BUCKETS = ((1 << 21, 1 << 18), (1 << 22, 1 << 19))
 MAX_PROBE_BYTES = 1 << 30
 
 
