@@ -23,13 +23,32 @@ compression only to the **unique** values — O(distinct) Python work, exact
 dict-key equality by construction.  ``combine_codes`` folds several code
 columns into one row key, re-compressing at each step so values stay far
 from int64 overflow.
+
+Most key columns need no sort.  Where a column is bool or integer, or
+float with every non-NaN value finite, integral and within ``+-2**53``,
+and its values span at most ``4 * len``, ``column_codes`` reads the codes
+off a presence bitmap over ``[min, max]`` (over ``[0, max]`` where the
+values already lie in ``[0, 4 * len]``): the rank of ``v`` among the
+present values, O(n + range).  That is exactly ``np.unique``'s inverse
+index: both count the distinct values below ``v``.  The cast to int64 is
+exact (integers within ``2**53`` are float64s), and ``-0.0`` casts to 0,
+so the zeros share a rank as they share a ``np.unique`` slot.  Distinct
+integers lie at least 1 apart, so no two share a 9-digit rounding and the
+``keyval`` remap is the identity.  NaNs take the numbers the sort gives
+them: after the ``k`` distinct values, ``k`` for every NaN, or under
+``nan_distinct`` ``k + 1`` on in row order (``np.unique``'s one NaN slot
+is counted first).  Every other column takes the sort; the choice reads
+only the data.
 """
 
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
+
+_EXACT = float(2**53)
+_INT64_MAX = int(np.iinfo(np.int64).max)
 
 
 def keyval(v):
@@ -53,11 +72,81 @@ def column_codes(arr: np.ndarray, *, nan_distinct: bool) -> np.ndarray:
     Object-dtype columns are not supported — callers fall back to the
     reference plane for those.
     """
+    return factorize(arr, nan_distinct=nan_distinct)[0]
+
+
+def factorize(arr: np.ndarray, *, nan_distinct: bool) -> Tuple[np.ndarray, bool]:
+    """``column_codes`` and whether they took the ``np.unique`` sort
+    (``False``: the sort-free rank path, or an empty column)."""
     arr = np.asarray(arr)
     if arr.dtype == object:
         raise TypeError("column_codes does not support object columns")
     if arr.size == 0:
-        return np.zeros(0, dtype=np.int64)
+        return np.zeros(0, dtype=np.int64), False
+    codes = _rank_codes(arr.reshape(-1), nan_distinct)
+    if codes is not None:
+        return codes, False
+    return _sorted_codes(arr, nan_distinct), True
+
+
+def _rank_codes(flat: np.ndarray, nan_distinct: bool) -> Optional[np.ndarray]:
+    """The sort-free codes of an integral column whose non-NaN values span
+    at most ``4 * len(flat)``, or ``None`` where the column is not one.
+
+    The codes are the ranks of the values among the distinct values, read
+    off a presence bitmap; NaNs are numbered after them as ``_sorted_codes``
+    numbers them.  Each numpy call releases the interpreter lock and waits
+    to take it back, which costs more than its pass while other threads
+    run Python, so the path makes few calls.
+    """
+    n = flat.size
+    kind = flat.dtype.kind
+    nan_mask = None
+    if kind == "f" and flat.dtype.itemsize <= 8:
+        vals = flat
+        lo, hi = float(vals.min()), float(vals.max())
+        if lo != lo:  # min and max propagate NaN: mask only where there is one
+            nan_mask = np.isnan(flat)
+            vals = flat[~nan_mask]
+            lo, hi = (float(vals.min()), float(vals.max())) if vals.size else (0.0, 0.0)
+        # inf fails the bounds; within +-2**53 every integer is a float64,
+        # so the cast below is exact where the value is integral
+        if not (-_EXACT <= lo and hi <= _EXACT) or hi - lo > 4 * n:
+            return None
+        off = vals.astype(np.int64)
+        if not (off == vals).all():
+            return None
+        lo, hi = int(lo), int(hi)
+    elif kind in "biu":
+        lo, hi = int(flat.min()), int(flat.max())
+        if hi - lo > 4 * n or hi > _INT64_MAX:
+            return None
+        off = flat.astype(np.int64, copy=False)
+    else:
+        return None
+    if lo < 0 or hi > 4 * n:  # values already in [0, 4n] index the bitmap as they are
+        off = off - lo
+        hi -= lo
+    present = np.zeros(hi + 1, dtype=bool)
+    present[off] = True
+    rank = np.empty(hi + 1, dtype=np.int64)  # distinct values below each offset
+    rank[0] = 0
+    np.cumsum(present[:-1], out=rank[1:])
+    codes = rank[off]
+    if nan_mask is None:
+        return codes
+    # _sorted_codes: np.unique's one NaN slot follows the k non-NaN values;
+    # nan_distinct numbers the NaN rows after that slot, in row order
+    k = int(rank[-1] + present[-1])  # the distinct values
+    out = np.empty(n, dtype=np.int64)
+    out[~nan_mask] = codes
+    n_nan = n - len(off)
+    out[nan_mask] = (k + 1 + np.arange(n_nan, dtype=np.int64)) if nan_distinct else k
+    return out
+
+
+def _sorted_codes(arr: np.ndarray, nan_distinct: bool) -> np.ndarray:
+    """``column_codes`` by ``np.unique``: any column."""
     uniq, inv = np.unique(arr, return_inverse=True)
     inv = inv.reshape(-1).astype(np.int64)
     # fast path: the keyval remap can only merge uniques beyond what

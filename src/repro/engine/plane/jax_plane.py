@@ -7,8 +7,9 @@ canonical numpy bytes).  Three design rules make that possible:
 
 1. **Dict-key canonicalization is unique-compressed, never re-derived.**
    Join keys, aggregate groups and distinct rows are factorized with
-   ``repro.engine.canon.column_codes`` — ``np.unique`` for the vectorized
-   part, the real Python ``round``/dict-equality applied only to the
+   ``repro.engine.canon.column_codes`` — ranks off a presence bitmap for
+   integral columns of narrow range, else ``np.unique`` for the vectorized
+   part and the real Python ``round``/dict-equality applied only to the
    unique values — so rounded-float collapse, ``-0.0 == 0.0`` and
    NaN-identity semantics match the reference exactly.
 
@@ -62,7 +63,7 @@ import numpy as np
 from repro import obs
 from repro.core import dag as D
 from repro.core.predicates import LinCmp, NonLinearAtom, Pred, StrEq
-from repro.engine.canon import column_codes, combine_codes, keyval
+from repro.engine.canon import column_codes, combine_codes, factorize, keyval
 from repro.engine.ops_impl import eval_linexpr, eval_pred
 from repro.engine.plane.base import DataPlane, PlaneError
 from repro.engine.plane.numpy_plane import NumpyPlane
@@ -504,19 +505,23 @@ class JaxPlane(DataPlane):
         # joint factorization: left and right key columns share one code
         # space per key position (dict-key equality incl. rounded collapse;
         # NaN keys get fresh codes so they never match — like the reference)
-        with obs.span("veer.plane.join.codes", nl=nl, nr=nr) as sp:
+        with obs.span("veer.plane.join.codes", nl=nl, nr=nr,
+                      keys=len(on)) as sp:
             code_cols = []
+            n_sorted = 0
             for lc, rc in zip(l_on, r_on):
                 both = np.concatenate(
                     [np.asarray(left.cols[lc]), np.asarray(r.cols[rc])]
                 )
-                code_cols.append(column_codes(both, nan_distinct=True))
+                codes, sorted_ = factorize(both, nan_distinct=True)
+                code_cols.append(codes)
+                n_sorted += sorted_
             joint = combine_codes(code_cols)
             lk, rk = joint[:nl], joint[nl:]
             max_code = int(joint.max()) if joint.size else 0
             # sparse codes go to the device probe (see below)
             device = max_code > max(1 << 22, 4 * (nl + nr))
-            sp.set_metadata(device=int(device))
+            sp.set_metadata(device=int(device), sorted=n_sorted)
 
         # probe: per-left-row windows [lo[i], hi[i]) into ``order`` — the
         # right indices stably sorted by key, so each window lists a key's

@@ -18,7 +18,8 @@ from repro.engine import (
     execute,
     tables_identical,
 )
-from repro.engine.canon import column_codes, combine_codes
+from repro import obs
+from repro.engine.canon import column_codes, combine_codes, factorize, keyval
 from repro.engine.executor import ExecutionPlan
 from repro.engine.ops_impl import _keyval, _stable_desc_fix
 from repro.engine.ops_impl import execute_op as ref_execute_op
@@ -691,6 +692,160 @@ def test_column_codes_nan_semantics():
     assert distinct[3] == distinct[4]  # -0.0 == 0.0
     collapsed = column_codes(arr, nan_distinct=False)
     assert collapsed[0] == collapsed[2]  # repr-keyed: all NaNs print "nan"
+
+
+def _sort_codes_oracle(arr, *, nan_distinct):
+    """``column_codes`` as it was before the sort-free path: ``np.unique``,
+    the ``keyval`` remap of the uniques where two may share a rounding,
+    NaN rows numbered after the uniques."""
+    arr = np.asarray(arr)
+    if arr.size == 0:
+        return np.zeros(0, dtype=np.int64)
+    uniq, inv = np.unique(arr, return_inverse=True)
+    inv = inv.reshape(-1).astype(np.int64)
+    merge_possible = False
+    n_slots = len(uniq)
+    if arr.dtype.kind == "f":
+        fu = uniq[~np.isnan(uniq)] if np.isnan(uniq[-1]) else uniq
+        merge_possible = len(fu) > 1 and float(np.min(np.diff(fu))) <= 1e-8
+    if not merge_possible:
+        codes = inv
+    else:
+        slots: dict = {}
+        remap = np.empty(len(uniq), dtype=np.int64)
+        for i, u in enumerate(uniq):
+            remap[i] = slots.setdefault(keyval(u), len(slots))
+        codes = remap[inv]
+        n_slots = len(slots)
+    if arr.dtype.kind == "f":
+        nan_mask = np.isnan(arr)
+        if nan_mask.any() and nan_distinct:
+            codes[nan_mask] = np.int64(n_slots) + np.arange(
+                int(nan_mask.sum()), dtype=np.int64
+            )
+    return codes
+
+
+def _codes_case(name):
+    """``(column, sorts)``: a key column and whether ``column_codes`` must
+    take the ``np.unique`` sort for it."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "shuffled_repeats":
+        return rng.permutation(np.repeat(rng.integers(1, 400, 300), 3)).astype(np.float64), False
+    if name == "negatives_and_zeros":
+        return np.array([-3.0, 0.0, -0.0, 5.0, -3.0, -0.0, 2.0, -1.0]), False
+    if name == "only_negative_zero":
+        return np.array([-0.0, 1.0, -0.0, -2.0]), False
+    if name in ("nan_front", "nan_middle", "nan_end"):
+        col = rng.integers(-20, 20, 60).astype(np.float64)
+        at = {"nan_front": [0, 1], "nan_middle": [29, 30, 45], "nan_end": [58, 59]}[name]
+        col[at] = np.nan
+        return col, False
+    if name == "all_nan":
+        return np.full(5, np.nan), False
+    if name == "single_value":
+        return np.full(7, 42.0), False
+    if name == "empty":
+        return np.zeros(0), False
+    if name == "int64":
+        return rng.integers(-(1 << 40), -(1 << 40) + 200, 100), False
+    if name == "uint8":
+        return rng.integers(0, 256, 100).astype(np.uint8), False
+    if name == "bool":
+        return rng.random(50) < 0.5, False
+    if name == "float32":
+        return rng.integers(-50, 50, 40).astype(np.float32), False
+    if name == "far_from_zero":
+        return 1e6 + rng.integers(0, 90, 30).astype(np.float64), False
+    if name == "near_2_52":  # a bitmap over [0, max] would take petabytes
+        return 2.0**52 + rng.integers(0, 90, 30).astype(np.float64), False
+    if name in ("range_at_4n", "range_over_4n"):
+        col = rng.integers(0, 40, 25).astype(np.float64)
+        col[[3, 11]] = (-5.0, -5.0 + 100 + (name == "range_over_4n"))
+        return col, name == "range_over_4n"
+    if name == "plus_inf":
+        return np.array([1.0, np.inf, 2.0, 1.0]), True
+    if name == "minus_inf":
+        return np.array([1.0, -np.inf, np.nan, 1.0]), True
+    if name == "beyond_2_53":
+        return np.array([2.0**53 + 2, 2.0**53 + 4, 2.0**53 + 2]), True
+    if name == "near_one":
+        return np.array([1.0, 1.0 + 1e-10, 2.0, 1.0 + 1e-10]), True
+    assert name == "halves"
+    return np.array([0.5, 1.5, 0.5, -2.5]), True
+
+
+@pytest.mark.parametrize("nan_distinct", [True, False])
+@pytest.mark.parametrize("case", [
+    "shuffled_repeats", "negatives_and_zeros", "only_negative_zero", "nan_front",
+    "nan_middle", "nan_end", "all_nan", "single_value", "empty", "int64", "uint8",
+    "bool", "float32", "far_from_zero", "near_2_52", "range_at_4n", "range_over_4n", "plus_inf", "minus_inf",
+    "beyond_2_53", "near_one", "halves",
+])
+def test_column_codes_equal_the_sort_based_oracle(case, nan_distinct):
+    col, sorts = _codes_case(case)
+    codes, sorted_ = factorize(col, nan_distinct=nan_distinct)
+    want = _sort_codes_oracle(col, nan_distinct=nan_distinct)
+    assert codes.dtype == np.int64 and np.array_equal(codes, want)
+    assert np.array_equal(column_codes(col, nan_distinct=nan_distinct), want)
+    assert sorted_ == sorts
+
+
+def _recorded_codes_spans(monkeypatch):
+    """The attributes of every ``veer.plane.join.codes`` span opened from
+    now on, as the span ends."""
+    seen = []
+
+    class _Rec:
+        def __init__(self, attrs):
+            self.attrs = attrs
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            seen.append(self.attrs)
+
+        def set_metadata(self, **attrs):
+            self.attrs.update(attrs)
+
+    real = obs.span
+
+    def span(name, **attrs):
+        return _Rec(dict(attrs)) if name == "veer.plane.join.codes" else real(name, **attrs)
+
+    monkeypatch.setattr(obs, "span", span)
+    return seen
+
+
+@pytest.mark.parametrize("fractional", [False, True])
+def test_sparse_three_key_join_on_rank_codes(monkeypatch, fractional):
+    """Three narrow integral key columns, with NaN and signed-zero keys,
+    whose combined codes are sparse enough for the device probe: the jax
+    plane's inner and left-outer joins equal the numpy plane's, and the
+    codes span counts the key columns and those that took the sort (one
+    where a column holds fractions)."""
+    rng = np.random.default_rng(16)
+    nl, nr = 3000, 1500
+    pool = rng.integers(-150, 150, (500, 3)).astype(np.float64)
+    pool[:40, 0] = -0.0
+    pool[40:80, 1] = 0.0
+    pool[80:90, 2] = np.nan
+    if fractional:
+        pool[:, 1] += 0.25
+    lkeys = pool[rng.integers(0, 500, nl)]
+    rkeys = pool[rng.integers(250, 750, nr) % 500]
+    lx = dict({f"k{i}": lkeys[:, i] for i in range(3)}, x=np.arange(float(nl)))
+    ry = dict({f"k{i}": rkeys[:, i] for i in range(3)}, y=np.arange(float(nr)))
+    on = tuple((f"k{i}", f"k{i}") for i in range(3))
+    sources = {"l": Table(lx, list(lx)), "r": Table(ry, list(ry))}
+    seen = _recorded_codes_spans(monkeypatch)
+    for how in ("inner", "left_outer"):
+        dag = _join_dag(how, schema_l=tuple(lx), schema_r=tuple(ry), on=on)
+        _assert_planes_identical(dag, sources)
+        assert ExecutionPlan(dag, sources, plane="jax").run().stats.ops_on_device == 1
+    assert seen and all(s["keys"] == 3 and s["sorted"] == int(fractional)
+                        and s["device"] == 1 for s in seen)
 
 
 def test_combine_codes_overflow_fold():
