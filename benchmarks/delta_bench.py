@@ -47,24 +47,18 @@ import pathlib
 import sys
 import time
 
-import numpy as np
-
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
 from repro.api import VeerConfig  # noqa: E402
-from repro.core import dag as D  # noqa: E402
-from repro.core.dag import DataflowDAG, Link, Operator  # noqa: E402
 from repro.core.ev.cache import VerdictCache  # noqa: E402
-from repro.core.predicates import Pred  # noqa: E402
 from repro.engine import (  # noqa: E402
     InMemoryMaterializationStore,
-    Table,
     execute,
     tables_identical,
 )
 from repro.service import VersionChainSession  # noqa: E402
+from repro.workload.flows import narrow_widen_chain, narrow_widen_sources  # noqa: E402
 
 BASELINE_PATH = pathlib.Path(__file__).resolve().parent / "BENCH_delta.json"
 # CI guard: the delta/reuse speedup ratio is machine-independent (both
@@ -77,61 +71,6 @@ FULL_ROWS = 1_000_000
 SMOKE_ROWS = 150_000
 MAX_DELTA_FRACTION = 0.10   # delta rows processed / input rows seen
 MIN_SPEEDUP = 3.0           # delta pass vs reuse pass, end to end
-
-# filter thresholds per version: narrow for 6 edits, widen back for 5.
-# All stay above the dominating downstream filter (b < 50), so every
-# consecutive pair is equivalent — the verifier certifies it, the
-# certificate grounds the delta tier, and each boundary delta is the
-# ~1.5%-of-rows band between consecutive thresholds.
-THRESHOLDS = (80.0, 78.5, 77.0, 75.5, 74.0, 72.5, 71.0,
-              72.0, 73.5, 75.0, 76.5, 78.0)
-DOMINATING = 50.0
-
-
-def build_version(threshold: float) -> DataflowDAG:
-    """One version of the bench spine; only ``fe``'s threshold varies.
-
-    src → fe (b < threshold, the edited filter) → fa (a > 2) →
-    fb (b < 50, dominates every threshold) → classifier → aggregate → sink.
-    The classifier+aggregate tail is the expensive part the reuse path
-    re-executes at full width and the delta path never touches.
-    """
-    ops = [
-        Operator.make("src", D.SOURCE, schema=("a", "b", "c")),
-        Operator.make("fe", D.FILTER, pred=Pred.cmp("b", "<", threshold)),
-        Operator.make("fa", D.FILTER, pred=Pred.cmp("a", ">", 2)),
-        Operator.make("fb", D.FILTER, pred=Pred.cmp("b", "<", DOMINATING)),
-        Operator.make("cl", D.CLASSIFIER, col="a", out="label",
-                      model="bench", classes=5),
-        Operator.make("agg", D.AGGREGATE, group_by=("label",),
-                      aggs=(("sum", "a", "sa"), ("sum", "c", "sc"),
-                            ("count", "*", "n"))),
-        Operator.make("sink", D.SINK, semantics=D.BAG),
-    ]
-    links = [Link("src", "fe"), Link("fe", "fa"), Link("fa", "fb"),
-             Link("fb", "cl"), Link("cl", "agg"), Link("agg", "sink")]
-    dag = DataflowDAG(ops, links)
-    dag.validate()
-    return dag
-
-
-def make_chain(versions: int = VERSIONS):
-    ths = [THRESHOLDS[k % len(THRESHOLDS)] for k in range(versions)]
-    return [build_version(th) for th in ths]
-
-
-def _sources(rows: int, seed: int = 0):
-    rng = np.random.default_rng(seed)
-    return {
-        "src": Table(
-            {
-                "a": rng.integers(0, 10, rows).astype(np.float64),
-                "b": rng.uniform(0.0, 100.0, rows),
-                "c": rng.integers(-5, 5, rows).astype(np.float64),
-            },
-            ["a", "b", "c"],
-        )
-    }
 
 
 def _pass(chain, sources, config, cache):
@@ -152,8 +91,8 @@ def run(versions: int = VERSIONS, rows: int = FULL_ROWS):
     """Returns ``(rows_out, headline)``; raises SystemExit on any identity,
     certification, amenability, or delta-volume violation."""
     config = VeerConfig(evs=("equitas", "spes", "udp"))
-    chain = make_chain(versions)
-    sources = _sources(rows)
+    chain = narrow_widen_chain(versions)
+    sources = narrow_widen_sources(rows)
 
     # -- warm the verdict cache: each pair's search is paid once here, so
     # both measured passes see the same (near-zero) verification cost

@@ -48,7 +48,6 @@ import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
 from repro.api import VeerConfig  # noqa: E402
 from repro.core.ev.cache import VerdictCache  # noqa: E402

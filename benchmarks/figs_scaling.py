@@ -6,7 +6,9 @@ import time
 from typing import Dict, List
 
 from benchmarks.common import baseline_veer, plus_veer, timed_verify
-from benchmarks.workloads import (
+from repro.core import dag as D
+from repro.core.dag import Operator
+from repro.workload.flows import (
     _B,
     _id_proj,
     apply_equivalent_edits,
@@ -14,8 +16,6 @@ from benchmarks.workloads import (
     build_workloads,
     edits_with_distance,
 )
-from repro.core import dag as D
-from repro.core.dag import Operator
 
 BUDGET = 4000
 

@@ -23,7 +23,7 @@ Three parts, all self-checking (non-zero exit on violation):
   (filter multiply/mask programs, projection accumulate, join probe) are
   compiled from shapes at the benchmark row count and reported against
   the published peaks of the device they compiled for
-  (``repro.launch.roofline.PEAKS``, keyed by ``device_kind``).  A device
+  (``repro.engine.plane.roofline.PEAKS``, keyed by ``device_kind``).  A device
   without published peaks (the CPU) gets no roofline terms.
 
 Usage (from the repo root):
@@ -46,12 +46,9 @@ import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
 from repro.api import VeerConfig  # noqa: E402
-from repro.core import dag as D  # noqa: E402
 from repro.core.ev.cache import VerdictCache  # noqa: E402
-from repro.core.predicates import LinCmp, LinExpr, Pred  # noqa: E402
 from repro.engine import (  # noqa: E402
     InMemoryMaterializationStore,
     Table,
@@ -61,6 +58,7 @@ from repro.engine import (  # noqa: E402
 from repro.engine.plane import get_plane  # noqa: E402
 from repro.service import VersionChainSession  # noqa: E402
 from repro.service.synthetic import make_chain  # noqa: E402
+from repro.workload.flows import hot_operator_flow, hot_operator_sources  # noqa: E402
 
 BASELINE_PATH = pathlib.Path(__file__).resolve().parent / "BENCH_plane.json"
 # CI guard: absolute wall-clock is runner-dependent; the committed baseline
@@ -77,107 +75,14 @@ SESSION_ROWS = 20_000
 # -- part A: hot-op chain -------------------------------------------------------
 
 
-def _hot_chain() -> D.DataflowDAG:
-    """Two sources, a branch, and every hot operator family once.
-
-    The shape mirrors a real iterative-analytics pipeline: a fused
-    filter+project front, a two-key left-outer join (the reference builds
-    per-row tuple dict keys for both sides), two deterministic "models",
-    a dictionary matcher, a two-column hash aggregate, a sort, plus a
-    distinct branch off the projection.
-    """
-    ops = [
-        D.Operator.make("s1", D.SOURCE, schema=("k", "k2", "g", "x")),
-        D.Operator.make("s2", D.SOURCE, schema=("k", "k2", "y")),
-        D.Operator.make(
-            "f1", D.FILTER,
-            pred=Pred.and_(
-                Pred.cmp("x", "<=", 5),
-                Pred.of(LinCmp(LinExpr.make({"g": -1, "x": 2}, 1), "<=")),
-            ),
-        ),
-        D.Operator.make(
-            "p1", D.PROJECT,
-            cols=(
-                ("k", "k"),
-                ("k2", "k2"),
-                ("g", "g"),
-                ("x2", LinExpr.make({"x": 2, "g": 1}, -0.5)),
-            ),
-        ),
-        D.Operator.make(
-            "j", D.JOIN, on=(("k", "k"), ("k2", "k2")), how="left_outer"
-        ),
-        D.Operator.make("cl", D.CLASSIFIER, col="g", classes=5, out="cls"),
-        D.Operator.make("se", D.SENTIMENT, col="x2", out="sent"),
-        D.Operator.make(
-            "dm", D.DICT_MATCHER, col="g",
-            entries=(1.0, 2.0, 3.0, 5.0, 8.0, 13.0, 21.0), out="hit",
-        ),
-        D.Operator.make(
-            "ag", D.AGGREGATE,
-            group_by=("g", "cls"),
-            aggs=(("sum", "x2", "sx"), ("count", "*", "cnt"), ("avg", "y", "ay")),
-        ),
-        D.Operator.make(
-            "so", D.SORT, keys=(("sx", True), ("g", True), ("cls", True))
-        ),
-        D.Operator.make("k1", D.SINK, semantics=D.ORDERED),
-        D.Operator.make("di", D.DISTINCT),
-        D.Operator.make("k2", D.SINK, semantics=D.BAG),
-    ]
-    links = [
-        D.Link("s1", "f1"),
-        D.Link("f1", "p1"),
-        D.Link("p1", "j", 0),
-        D.Link("s2", "j", 1),
-        D.Link("j", "cl"),
-        D.Link("cl", "se"),
-        D.Link("se", "dm"),
-        D.Link("dm", "ag"),
-        D.Link("ag", "so"),
-        D.Link("so", "k1"),
-        D.Link("p1", "di"),
-        D.Link("di", "k2"),
-    ]
-    return D.DataflowDAG(ops=ops, links=links)
-
-
-def _hot_sources(rows: int, seed: int = 0):
-    rng = np.random.default_rng(seed)
-    n2 = max(rows // 4, 1)
-    return {
-        # high-cardinality primary keys + a low-cardinality secondary key
-        # (most left rows unmatched: the outer-pad path is exercised),
-        # mid-cardinality groups, small-domain filter values
-        "s1": Table(
-            {
-                "k": rng.integers(0, rows, rows).astype(np.float64),
-                "k2": rng.integers(0, 4, rows).astype(np.float64),
-                "g": rng.integers(0, 1024, rows).astype(np.float64),
-                "x": rng.integers(0, 7, rows).astype(np.float64),
-            },
-            ["k", "k2", "g", "x"],
-        ),
-        "s2": Table(
-            {
-                "k": rng.integers(0, rows, n2).astype(np.float64),
-                "k2": rng.integers(0, 4, n2).astype(np.float64),
-                "y": rng.integers(0, 7, n2).astype(np.float64),
-            },
-            ["k", "k2", "y"],
-        ),
-    }
-
-
 def run_chain(rows: int):
-    dag = _hot_chain()
-    sources = _hot_sources(rows)
+    dag = hot_operator_flow()
+    sources = hot_operator_sources(rows)
 
     # warm the jax plane at full size first: jit specializes per operand
     # shape, so the compile (a one-time process cost the numpy plane has
     # no analogue of) is excluded from the measurement, like any warmup
-    warm = _hot_sources(rows, seed=1)
+    warm = hot_operator_sources(rows, seed=1)
     execute(dag, warm, plane="jax")
 
     t0 = time.perf_counter()
@@ -286,7 +191,7 @@ def run_session(rows: int = SESSION_ROWS, versions: int = SESSION_VERSIONS):
 def run_roofline(rows: int):
     import jax
 
-    from repro.launch.roofline import PEAKS
+    from repro.engine.plane.roofline import PEAKS
 
     dev = jax.devices()[0]
     where = f"{dev.platform} / {dev.device_kind} x{len(jax.devices())}"

@@ -39,7 +39,6 @@ def main() -> None:
         exec_bench,
         figs_scaling,
         plane_bench,
-        roofline_bench,
         search_bench,
         service_bench,
         session_bench,
@@ -190,18 +189,6 @@ def main() -> None:
         f"speedup={headline['speedup']:.1f}x "
         f"@{headline['changes']}changes",
     ))
-
-    print("\n== Roofline table (single-pod baseline) ==")
-    t0 = time.perf_counter()
-    rows = roofline_bench.run()
-    ok = [r for r in rows if r.get("status") == "ok"]
-    if ok:
-        worst = min(ok, key=lambda r: r["roofline_frac"])
-        csv_lines.append(_csv(
-            "roofline_baseline", time.perf_counter() - t0,
-            f"cells={len(ok)} worst={worst['arch']}/{worst['shape']}"
-            f"@{worst['roofline_frac']:.4f}",
-        ))
 
     print("\n== CSV summary ==")
     print("name,us_per_call,derived")

@@ -39,13 +39,12 @@ import time
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
-from benchmarks.workloads import apply_equivalent_edits, build_workloads  # noqa: E402
 from repro.api import default_registry  # noqa: E402
 from repro.api.certificate import certificate_from_evidence  # noqa: E402
 from repro.core.ev.cache import VerdictCache  # noqa: E402
 from repro.core.verifier import Veer  # noqa: E402
+from repro.workload.flows import apply_equivalent_edits, build_workloads  # noqa: E402
 
 BASELINE_PATH = pathlib.Path(__file__).resolve().parent / "BENCH_search.json"
 GUIDED_BASELINE_PATH = pathlib.Path(__file__).resolve().parent / "BENCH_guided.json"

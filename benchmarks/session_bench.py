@@ -44,7 +44,6 @@ import time
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
 from repro.service import VersionChainSession  # noqa: E402
 from repro.workload import (  # noqa: E402

@@ -10,12 +10,12 @@ from __future__ import annotations
 import time
 from typing import Dict, List
 
-from benchmarks.workloads import apply_equivalent_edits, build_workloads, _B, _id_proj
 from repro.api import default_registry
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG
 from repro.core.edits import identity_mapping
 from repro.core.window import VersionPair
+from repro.workload.flows import apply_equivalent_edits, build_workloads, _B, _id_proj
 
 
 def _calcite_like() -> Dict[str, DataflowDAG]:

@@ -6,7 +6,7 @@ import time
 from typing import Dict, List
 
 from benchmarks.common import baseline_veer, plus_veer, spes_direct, timed_verify
-from benchmarks.workloads import (
+from repro.workload.flows import (
     apply_equivalent_edits,
     apply_inequivalent_edits,
     build_workloads,

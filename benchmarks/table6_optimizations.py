@@ -16,8 +16,8 @@ def PAPER_SET():
     return default_registry().build(["equitas", "spes", "udp"])
 
 
-from benchmarks.workloads import apply_equivalent_edits, build_workloads
 from repro.core.verifier import Veer
+from repro.workload.flows import apply_equivalent_edits, build_workloads
 
 BUDGET = 25_000
 
@@ -25,7 +25,7 @@ BUDGET = 25_000
 def _w3_three_edits():
     """Paper setup: one edit after the (EV-unsupported) Sort, two before —
     so segmentation splits the decomposition space at the Sort boundary."""
-    from benchmarks.workloads import _splice, op, _schema_at, _id_proj
+    from repro.workload.flows import _splice, op, _schema_at, _id_proj
     from repro.core import dag as D
     from repro.core.dag import Link
 

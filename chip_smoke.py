@@ -11,9 +11,9 @@ is caught, so any failure exits non-zero before the result line.
   3. session  6-version heavy chain through VersionChainSession on the jax
               plane (exec_mode="reuse"): sinks byte-identical to full
               numpy-plane execution, every certificate replays green
-  4. delta    the narrow/widen filter chain of benchmarks/delta_bench.py
-              with exec_mode="delta": sinks identical, every pair certified
-  5. hot      benchmarks/plane_bench.py's hot-operator chain (warm-up call,
+  4. delta    the narrow/widen filter chain (repro.workload.flows) with
+              exec_mode="delta": sinks identical, every pair certified
+  5. hot      the hot-operator flow (repro.workload.flows; warm-up call,
               then the timed call) plus a join whose combined key codes are
               sparse, so the jitted join probe runs on the chip
   6. fleet    a 2-worker VerificationFleet (host processes, default plane)
@@ -46,9 +46,7 @@ import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
-from benchmarks import delta_bench, plane_bench  # noqa: E402
 from repro.api import VeerConfig  # noqa: E402
 from repro.api.registry import default_registry  # noqa: E402
 from repro.core import dag as D  # noqa: E402
@@ -63,6 +61,12 @@ from repro.engine.plane import get_plane  # noqa: E402
 from repro.engine.plane.jax_plane import use_compile_cache  # noqa: E402
 from repro.service import VerificationFleet, VersionChainSession  # noqa: E402
 from repro.service.synthetic import make_chain  # noqa: E402
+from repro.workload.flows import (  # noqa: E402
+    hot_operator_flow,
+    hot_operator_sources,
+    narrow_widen_chain,
+    narrow_widen_sources,
+)
 
 FULL_ROWS = 1_000_000
 KERNEL_BUCKET = 1 << 20  # the power-of-two bucket a 1M-row table pads to
@@ -179,8 +183,8 @@ def phase_session(rows: int) -> None:
 
 def phase_delta(rows: int) -> None:
     t0 = time.perf_counter()
-    chain = delta_bench.make_chain()
-    sources = delta_bench._sources(rows)
+    chain = narrow_widen_chain()
+    sources = narrow_widen_sources(rows)
     session = VersionChainSession(
         config=VeerConfig(evs=("equitas", "spes", "udp"), plane="jax",
                           exec_mode="delta"),
@@ -223,9 +227,9 @@ def _sparse_join(s1: Table, seed: int = 2):
 
 def phase_hot(rows: int) -> None:
     t0 = time.perf_counter()
-    dag = plane_bench._hot_chain()
-    execute(dag, plane_bench._hot_sources(rows, seed=1), plane="jax")  # warm-up
-    sources = plane_bench._hot_sources(rows)
+    dag = hot_operator_flow()
+    execute(dag, hot_operator_sources(rows, seed=1), plane="jax")  # warm-up
+    sources = hot_operator_sources(rows)
     t = time.perf_counter()
     res = ExecutionPlan(dag, sources, plane="jax").run()
     t_jax = time.perf_counter() - t

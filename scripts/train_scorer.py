@@ -31,7 +31,6 @@ import time
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT))
 
 from repro.learn import PRETRAINED_PATH, harvest, load_guidance, train_guidance  # noqa: E402
 from repro.workload import dump_windows, load_windows  # noqa: E402

@@ -42,16 +42,7 @@ DICT_MATCHER = "DictionaryMatcher"  # properties: col, entries, out
 CLASSIFIER = "Classifier"     # properties: col, model, out
 SENTIMENT = "SentimentAnalyzer"
 
-# Framework compute operators (the expensive steps Veer makes reusable)
-TRAIN_STEP = "TrainStep"      # properties: arch, shape, steps
-SERVE_STEP = "ServeStep"
-TOKENIZE = "TokenizePack"     # data-pipeline operator
-
 SINK = "Sink"                 # properties: semantics in {set,bag,ordered}
-
-RELATIONAL_OPS = {SOURCE, FILTER, PROJECT, JOIN, AGGREGATE, UNION, DISTINCT,
-                  SORT, LIMIT, UNNEST, REPLICATE, SINK}
-ML_OPS = {UDF, DICT_MATCHER, CLASSIFIER, SENTIMENT, TRAIN_STEP, SERVE_STEP, TOKENIZE}
 
 _ARITY = {JOIN: 2, UNION: 2}   # everything else: 1 input (SOURCE: 0)
 
@@ -382,8 +373,4 @@ def _op_schema(
             return list(out_schema)
         adds = op.get("adds", ())
         return list(ins[0]) + list(adds)
-    if t in (TRAIN_STEP, SERVE_STEP):
-        return list(op.get("out_schema", ("metrics",)))
-    if t == TOKENIZE:
-        return ["tokens", "doc_id"]
     raise DAGError(f"no schema rule for {t}")

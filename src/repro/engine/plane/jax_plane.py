@@ -300,7 +300,7 @@ class JaxPlane(DataPlane):
 
     def _compile_pred(self, pred: Pred) -> Optional[_PredPlan]:
         _, jnp = _modules()
-        from repro.kernels.relational import build_elementwise
+        from repro.engine.plane.kernels import build_elementwise
 
         lin_atoms: List[LinCmp] = []
         host_atoms: List = []
@@ -427,7 +427,7 @@ class JaxPlane(DataPlane):
 
     def _compile_proj(self, cols) -> Optional[_ProjPlan]:
         _, jnp = _modules()
-        from repro.kernels.relational import build_elementwise
+        from repro.engine.plane.kernels import build_elementwise
 
         prods_spec: List[Tuple[str, float]] = []
         items: List[Tuple[str, str, object]] = []
@@ -552,7 +552,7 @@ class JaxPlane(DataPlane):
             hi = ends_all[lk]
         else:
             _, jnp = _modules()
-            from repro.kernels.relational import pow2_bucket
+            from repro.engine.plane.kernels import pow2_bucket
 
             sentinel = np.int64(1) << 62
             lk_p = np.full(pow2_bucket(nl), sentinel, dtype=np.int64)
@@ -758,7 +758,7 @@ class JaxPlane(DataPlane):
         (consumed by ``benchmarks/plane_bench.py``); a device without
         published peaks raises ``UnknownDevice``.  Kernels are compiled
         from shapes — no device allocation."""
-        from repro.launch.roofline import kernel_roofline
+        from repro.engine.plane.roofline import kernel_roofline
 
         jax, _ = _modules()
         kind = jax.devices()[0].device_kind

@@ -17,8 +17,10 @@ import json
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
+from repro.workload.flows import WORKLOADS
+
 # the edit families the session generator samples from (ISSUE 6 + 10):
-#   equivalent   — Calcite-preserving rewrites (benchmarks/workloads.py)
+#   equivalent   — Calcite-preserving rewrites (workload/flows.py)
 #   semantic     — TPC-DS-iterative semantic edits (ground truth unknown:
 #                  a dropped projection column may be provably unused)
 #   boundary     — two empty-filter edits 0-2 hops apart, stressing window
@@ -120,8 +122,6 @@ class WorkloadConfig:
 
     # -- validation ----------------------------------------------------------
     def validate(self) -> "WorkloadConfig":
-        from benchmarks.workloads import WORKLOADS  # late: avoids cycles
-
         if not isinstance(self.seed, int):
             raise WorkloadConfigError(f"seed must be an int, got {self.seed!r}")
         for f in ("sessions", "clients", "chain_length", "max_edits_per_version",

@@ -1,4 +1,5 @@
-"""Benchmark workloads W1-W8 (paper Table 4) + edit generators.
+"""The flows W1-W8 (paper Table 4), their edit generators, and the fixed
+flows of the data-plane and delta drivers.
 
 Shapes mirror the paper's table: op counts, join/aggregate/union/replicate
 mix, and the semantically-rich operators (UDF, Classifier, Sort, Unnest)
@@ -483,3 +484,152 @@ def edits_with_distance(
             q = _splice(q, tail, op(f"{prefix}_b", D.FILTER, pred=Pred.true()))
             return q
     raise ValueError(f"no chain with {hops} hops in workflow")
+
+
+# ---------------------------------------------------------------------------
+# Fixed flows of the data-plane and delta drivers
+# ---------------------------------------------------------------------------
+
+# filter thresholds per version: narrow for 6 edits, widen back for 5.
+# All stay above the dominating downstream filter (b < 50), so every
+# consecutive pair is equivalent — the verifier certifies it, the
+# certificate grounds the delta tier, and each boundary delta is the
+# ~1.5%-of-rows band between consecutive thresholds.
+NARROW_WIDEN_THRESHOLDS = (80.0, 78.5, 77.0, 75.5, 74.0, 72.5, 71.0,
+                           72.0, 73.5, 75.0, 76.5, 78.0)
+_DOMINATING = 50.0
+
+
+def _narrow_widen_version(threshold: float) -> DataflowDAG:
+    """One version of the narrow/widen spine; only ``fe``'s threshold varies.
+
+    src → fe (b < threshold, the edited filter) → fa (a > 2) →
+    fb (b < 50, dominates every threshold) → classifier → aggregate → sink.
+    The classifier+aggregate tail is the expensive part the reuse path
+    re-executes at full width and the delta path never touches.
+    """
+    ops = [
+        op("src", D.SOURCE, schema=("a", "b", "c")),
+        op("fe", D.FILTER, pred=Pred.cmp("b", "<", threshold)),
+        op("fa", D.FILTER, pred=Pred.cmp("a", ">", 2)),
+        op("fb", D.FILTER, pred=Pred.cmp("b", "<", _DOMINATING)),
+        op("cl", D.CLASSIFIER, col="a", out="label", model="bench", classes=5),
+        op("agg", D.AGGREGATE, group_by=("label",),
+           aggs=(("sum", "a", "sa"), ("sum", "c", "sc"), ("count", "*", "n"))),
+        op("sink", D.SINK, semantics=D.BAG),
+    ]
+    links = [Link("src", "fe"), Link("fe", "fa"), Link("fa", "fb"),
+             Link("fb", "cl"), Link("cl", "agg"), Link("agg", "sink")]
+    dag = DataflowDAG(ops, links)
+    dag.validate()
+    return dag
+
+
+def narrow_widen_chain(versions: int = 12) -> List[DataflowDAG]:
+    """A chain of predicate narrow/widen edits near the top of a heavy
+    spine: every pair is equivalent and delta-amenable."""
+    ths = NARROW_WIDEN_THRESHOLDS
+    return [_narrow_widen_version(ths[k % len(ths)]) for k in range(versions)]
+
+
+def narrow_widen_sources(rows: int, seed: int = 0) -> Dict[str, Table]:
+    rng = np.random.default_rng(seed)
+    return {
+        "src": Table(
+            {
+                "a": rng.integers(0, 10, rows).astype(np.float64),
+                "b": rng.uniform(0.0, 100.0, rows),
+                "c": rng.integers(-5, 5, rows).astype(np.float64),
+            },
+            ["a", "b", "c"],
+        )
+    }
+
+
+def hot_operator_flow() -> DataflowDAG:
+    """Two sources, a branch, and every hot operator family once.
+
+    The shape mirrors a real iterative-analytics pipeline: a fused
+    filter+project front, a two-key left-outer join (the reference builds
+    per-row tuple dict keys for both sides), two deterministic "models",
+    a dictionary matcher, a two-column hash aggregate, a sort, plus a
+    distinct branch off the projection.
+    """
+    ops = [
+        op("s1", D.SOURCE, schema=("k", "k2", "g", "x")),
+        op("s2", D.SOURCE, schema=("k", "k2", "y")),
+        op(
+            "f1", D.FILTER,
+            pred=Pred.and_(
+                Pred.cmp("x", "<=", 5),
+                Pred.of(LinCmp(LinExpr.make({"g": -1, "x": 2}, 1), "<=")),
+            ),
+        ),
+        op(
+            "p1", D.PROJECT,
+            cols=(
+                ("k", "k"),
+                ("k2", "k2"),
+                ("g", "g"),
+                ("x2", LinExpr.make({"x": 2, "g": 1}, -0.5)),
+            ),
+        ),
+        op("j", D.JOIN, on=(("k", "k"), ("k2", "k2")), how="left_outer"),
+        op("cl", D.CLASSIFIER, col="g", classes=5, out="cls"),
+        op("se", D.SENTIMENT, col="x2", out="sent"),
+        op(
+            "dm", D.DICT_MATCHER, col="g",
+            entries=(1.0, 2.0, 3.0, 5.0, 8.0, 13.0, 21.0), out="hit",
+        ),
+        op(
+            "ag", D.AGGREGATE,
+            group_by=("g", "cls"),
+            aggs=(("sum", "x2", "sx"), ("count", "*", "cnt"), ("avg", "y", "ay")),
+        ),
+        op("so", D.SORT, keys=(("sx", True), ("g", True), ("cls", True))),
+        op("k1", D.SINK, semantics=D.ORDERED),
+        op("di", D.DISTINCT),
+        op("k2", D.SINK, semantics=D.BAG),
+    ]
+    links = [
+        Link("s1", "f1"),
+        Link("f1", "p1"),
+        Link("p1", "j", 0),
+        Link("s2", "j", 1),
+        Link("j", "cl"),
+        Link("cl", "se"),
+        Link("se", "dm"),
+        Link("dm", "ag"),
+        Link("ag", "so"),
+        Link("so", "k1"),
+        Link("p1", "di"),
+        Link("di", "k2"),
+    ]
+    return DataflowDAG(ops=ops, links=links)
+
+
+def hot_operator_sources(rows: int, seed: int = 0) -> Dict[str, Table]:
+    rng = np.random.default_rng(seed)
+    n2 = max(rows // 4, 1)
+    return {
+        # high-cardinality primary keys + a low-cardinality secondary key
+        # (most left rows unmatched: the outer-pad path is exercised),
+        # mid-cardinality groups, small-domain filter values
+        "s1": Table(
+            {
+                "k": rng.integers(0, rows, rows).astype(np.float64),
+                "k2": rng.integers(0, 4, rows).astype(np.float64),
+                "g": rng.integers(0, 1024, rows).astype(np.float64),
+                "x": rng.integers(0, 7, rows).astype(np.float64),
+            },
+            ["k", "k2", "g", "x"],
+        ),
+        "s2": Table(
+            {
+                "k": rng.integers(0, rows, n2).astype(np.float64),
+                "k2": rng.integers(0, 4, n2).astype(np.float64),
+                "y": rng.integers(0, 7, n2).astype(np.float64),
+            },
+            ["k", "k2", "y"],
+        ),
+    }

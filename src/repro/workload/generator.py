@@ -6,7 +6,7 @@ versions.  ``SessionGenerator`` samples such chains over the paper's W1-W8
 shapes, drawing each step from five edit families:
 
   * ``equivalent``   — Calcite-preserving rewrites
-    (``benchmarks.workloads.apply_equivalent_edits``); the pair is
+    (``repro.workload.flows.apply_equivalent_edits``); the pair is
     equivalent *by construction*, so the differential oracle may demand an
     execution-equal sink on every source binding.
   * ``semantic``     — TPC-DS-iterative semantic edits
@@ -40,13 +40,6 @@ import random
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
-from benchmarks.workloads import (
-    WORKLOADS,
-    apply_equivalent_edits,
-    apply_inequivalent_edits,
-    edits_with_distance,
-    random_tables,
-)
 from repro.api.serialize import dag_to_dict
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG, Link, Operator
@@ -54,6 +47,13 @@ from repro.core.edits import EditMapping
 from repro.engine.store import table_digest
 from repro.engine.table import Table
 from repro.workload.config import WorkloadConfig
+from repro.workload.flows import (
+    WORKLOADS,
+    apply_equivalent_edits,
+    apply_inequivalent_edits,
+    edits_with_distance,
+    random_tables,
+)
 
 # expected verdict classes a planned pair can carry:
 #   "eq"  — equivalent by construction; a False verdict or an

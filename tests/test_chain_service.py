@@ -123,9 +123,13 @@ def test_session_arg_validation(tmp_path):
 
 
 def test_chain_bench_smoke():
-    import sys, pathlib
+    import pathlib
+    import subprocess
+    import sys
 
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
-    from benchmarks import chain_bench
-
-    assert chain_bench.main(["--smoke", "--versions", "4"]) == 0
+    script = pathlib.Path(__file__).resolve().parent.parent / "benchmarks" / "chain_bench.py"
+    out = subprocess.run(
+        [sys.executable, str(script), "--smoke", "--versions", "4"],
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]

@@ -597,3 +597,18 @@ def test_seeded_randomized_chain_differential(seed):
     versions = make_chain(n_versions, branches=branches)
     sources = _sources_for(versions[0], seed=seed + 100, n=60)
     _run_chain_differential(versions, sources, semantics)
+
+
+def test_data_pipeline_deterministic_and_packs():
+    from repro.data import corpus_table, ingestion_pipeline, pack_batches
+
+    corpus = corpus_table(64)
+    dag = ingestion_pipeline(min_quality=0.3, lang=1)
+    r1 = execute(dag, {"corpus": corpus})["packed"]
+    r2 = execute(dag, {"corpus": corpus})["packed"]
+    assert r1.rows() == r2.rows()
+    batches = list(pack_batches(r1, seq_len=32, batch=2, vocab=1000))
+    assert batches, "pipeline produced no batches"
+    for b in batches:
+        assert b["tokens"].shape == (2, 33)
+        assert (b["tokens"] >= 0).all() and (b["tokens"] < 1000).all()

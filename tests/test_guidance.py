@@ -22,7 +22,6 @@ import json
 
 import pytest
 
-from benchmarks.workloads import apply_equivalent_edits, build_workloads
 from repro.api import default_registry
 from repro.api.certificate import Certificate, certificate_from_evidence
 from repro.api.config import ConfigError, VeerConfig
@@ -42,6 +41,7 @@ from repro.learn import (
 from repro.learn.train import _example_from_window, harvest
 from repro.workload import WorkloadConfig, dedupe_windows, default_veer_config
 from repro.workload.corpus import WindowExample
+from repro.workload.flows import apply_equivalent_edits, build_workloads
 
 BUDGET = 3_000
 

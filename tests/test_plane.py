@@ -199,7 +199,7 @@ def test_failed_exactness_probe_is_visible(monkeypatch):
     probe: the plane says so (flag, first differing value, one warning),
     runs FILTER and PROJECT on the host, and counts nothing on the device
     — and the sinks stay byte-identical."""
-    import repro.kernels.relational as rel
+    import repro.engine.plane.kernels as rel
     from repro.engine import plane as plane_mod
     from repro.engine.plane.jax_plane import JaxPlane
 
@@ -409,7 +409,7 @@ def test_sparse_code_join_over_several_probe_blocks():
     """A sparse-code join whose right side fills many probe blocks, with
     runs of equal keys both inside a block and longer than one."""
     from repro.engine.plane.jax_plane import probe_layout
-    from repro.kernels.relational import pow2_bucket
+    from repro.engine.plane.kernels import pow2_bucket
 
     rng = np.random.default_rng(14)
     pool = rng.integers(0, 1 << 40, (400, 3)).astype(np.float64)
@@ -873,7 +873,7 @@ def test_combine_codes_overflow_fold():
 
 
 def test_build_elementwise_bucket_padding_is_exact():
-    from repro.kernels.relational import build_elementwise
+    from repro.engine.plane.kernels import build_elementwise
 
     def body(x, y):
         return x + y, (x + y) <= 2.0
@@ -890,7 +890,7 @@ def test_build_elementwise_bucket_padding_is_exact():
 
 
 def test_pow2_bucket():
-    from repro.kernels.relational import pow2_bucket
+    from repro.engine.plane.kernels import pow2_bucket
 
     assert pow2_bucket(0) == 1
     assert pow2_bucket(1) == 1

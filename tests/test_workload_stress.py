@@ -107,11 +107,11 @@ def test_different_seeds_generate_different_sessions():
 
 
 def test_edit_generators_are_seed_deterministic():
-    """The threaded-rng contract of benchmarks.workloads: same explicit
+    """The threaded-rng contract of repro.workload.flows: same explicit
     seed ⇒ byte-identical edited DAG, no module-level random state."""
     import random
 
-    from benchmarks.workloads import (
+    from repro.workload.flows import (
         apply_equivalent_edits,
         apply_inequivalent_edits,
         build_workloads,
