@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import hashlib
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, FrozenSet, Iterator, List, Optional, Sequence, Tuple
 
 from repro import obs
@@ -46,6 +46,14 @@ class QueryPair:
             self.semantics,
             self.at_version_sink,
         )
+
+    def materialized(self) -> "QueryPair":
+        """This pair with ``P`` and ``Q`` as ``DataflowDAG``s: itself,
+        unless its sides are views of a window over the version DAGs
+        (``repro.core.window.WindowDAG``), whose sub-DAGs are then built."""
+        if isinstance(self.P, DataflowDAG) and isinstance(self.Q, DataflowDAG):
+            return self
+        return replace(self, P=self.P.materialize(), Q=self.Q.materialize())
 
     def fingerprint(self) -> str:
         """Content-addressed canonical key, invariant under operator renames.

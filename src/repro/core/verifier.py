@@ -126,6 +126,12 @@ class VeerStats:
     # decided before in the same pair is looked up, not counted again
     # (``repro.core.ev.memo``)
     sat_calls: int = 0
+    # windows whose boundary the search read to admit them (Alg 2 line 9),
+    # and the sub-DAG pairs built for them: the bitmask kernel reads a
+    # window through the version DAGs and builds its sub-DAGs only for a
+    # certificate or an EV that asks for them (``repro.core.window.WindowDAG``)
+    windows_admitted: int = 0
+    subdags: int = 0
     explore_time: float = 0.0
     total_time: float = 0.0
     segments: int = 0
@@ -319,9 +325,11 @@ class Veer:
                 coll = _EvidenceCollector()
                 coll.pair = pair
                 verdict = self._verify_pair(pair, stats, coll)
+                if verdict is not UNKNOWN and collect:
+                    evidence = _assemble_evidence(verdict, coll)
+                stats.windows_admitted += pair.windows_admitted
+                stats.subdags += pair.subdags
                 if verdict is not UNKNOWN:
-                    if collect:
-                        evidence = _assemble_evidence(verdict, coll)
                     break
         stats.sat_calls = len(pair_memo.sat)
         stats.total_time = time.perf_counter() - t0
@@ -629,7 +637,7 @@ class Veer:
         table = ctx.table
         if not table.connected(wid):
             return False
-        if table.query_pair(wid) is None:
+        if table.boundary(wid) is None:
             return True  # ill-formed: must keep growing
         if ctx.valid_evs(wid):
             return True
@@ -874,7 +882,9 @@ def _identity_payload(
 
 def _window_evidence(ctx: BaseSearchContext, win) -> WindowEvidence:
     """``win`` is a backend window handle (table id or frozenset); the
-    emitted evidence is representation-free and byte-identical either way."""
+    emitted evidence is representation-free and byte-identical either way
+    (the kernel's query pair is read through the version DAGs until here,
+    where its sub-DAGs are built)."""
     kind, ev_name = ctx.provenance.get(win, ("identical", None))
     verdict = ctx._verdict.get(win)
     if kind == "identical":
@@ -890,7 +900,7 @@ def _window_evidence(ctx: BaseSearchContext, win) -> WindowEvidence:
         verdict=verdict,
         ev_name=ev_name,
         fingerprint=ctx.fingerprint(win),
-        query_pair=ctx.query_pair(win),
+        query_pair=ctx.query_pair(win).materialized(),
     )
 
 

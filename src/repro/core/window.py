@@ -38,17 +38,25 @@ inner-loop operations become single big-int instructions:
 
 ``WindowTable`` interns masks to dense small-int ids and caches everything
 the verifier repeatedly asks about a window (sort key, popcount, neighbor
-mask, connectivity, query pair, fingerprint, valid-EV list, covered-change
-mask), so the decomposition search operates on small ints end to end.
-``FrozenSet`` survives only at the public API boundary (``to_query_pair``,
-``window_fingerprint``, certificate replay): the exported query pairs and
-evidence are byte-identical either way.
+mask, connectivity, boundary, query pair, fingerprint, valid-EV list,
+covered-change mask), so the decomposition search operates on small ints end
+to end.  ``FrozenSet`` survives only at the public API boundary
+(``to_query_pair``, ``window_fingerprint``, certificate replay): the exported
+query pairs and evidence are byte-identical either way.
+
+The search admits thousands of windows a pair and hands few of them to an
+EV, so it builds no sub-DAGs for them: admission reads only the boundary
+(``window_boundary``), and a well-formed window's query pair reads ``P`` and
+``Q`` through the window (``WindowDAG``).  The sub-DAGs themselves
+(``to_query_pair``'s) are built for a certificate, or for an EV that asks
+for more of a ``DataflowDAG`` than its operators and links.
 """
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
-from typing import Dict, FrozenSet, List, Mapping, Optional, Sequence, Set, Tuple
+from typing import Dict, FrozenSet, List, Mapping, NamedTuple, Optional, Sequence, Set, Tuple
 
 from repro.core import dag as D
 from repro.core.dag import DataflowDAG, Link, Operator, infer_schema
@@ -100,8 +108,12 @@ class VersionPair:
         Q.validate()
         self.P, self.Q, self.mapping = P, Q, mapping
         self.semantics = semantics
-        # the windows' symbolic sources, by (side, id)
-        self._symbolic: Dict[Tuple[str, str], Operator] = {}
+        # windows whose boundary was read, and sub-DAG pairs built for them
+        # (``VeerStats.windows_admitted`` / ``subdags``); an EV on a worker
+        # thread may build sub-DAGs, hence the lock
+        self.windows_admitted = 0
+        self.subdags = 0
+        self._subdags_lock = threading.Lock()
         fwd = mapping.forward
         bwd = mapping.backward
 
@@ -158,6 +170,7 @@ class VersionPair:
         self.change_masks = [self.mask_of(c.required_units) for c in self.changes]
         self.schemas_p = infer_schema(P, {})
         self.schemas_q = infer_schema(Q, {})
+        self._inputs = {side: self._window_inputs(side) for side in ("p", "q")}
         self._qp_cache: Dict[FrozenSet[int], Optional[QueryPair]] = {}
         self._fp_cache: Dict[FrozenSet[int], str] = {}
 
@@ -458,12 +471,43 @@ class VersionPair:
         self._fp_cache[win] = fp
         return fp
 
-    def _build_query_pair(
+    def _build_query_pair(self, win: FrozenSet[int]) -> Optional[QueryPair]:
+        b = self.window_boundary(win)
+        if b is None:
+            return None
+        P_sub, Q_sub = self._sub_dags(b.p_in, b.q_in)
+        return QueryPair(
+            P_sub,
+            Q_sub,
+            b.sink_pairs,
+            semantics=self.semantics,
+            at_version_sink=b.at_version_sink,
+        )
+
+    def window_query_pair(self, b: "WindowBoundary") -> QueryPair:
+        """The query pair of a well-formed window, its two sides read
+        through ``P`` and ``Q`` (``WindowDAG``): equal, for every reader,
+        to ``to_query_pair``'s, with no sub-DAG built until one is asked
+        for."""
+        sides = _WindowSides(self, b.p_in, b.q_in)
+        return QueryPair(
+            WindowDAG(sides, "p"),
+            WindowDAG(sides, "q"),
+            b.sink_pairs,
+            semantics=self.semantics,
+            at_version_sink=b.at_version_sink,
+        )
+
+    def window_boundary(
         self, win: FrozenSet[int], *, assume_connected: bool = False
-    ) -> Optional[QueryPair]:
-        """``assume_connected=True`` skips the Def 3.1 connectivity recheck —
-        the ``WindowTable`` fast path has already established it via
-        ``mask_connected`` (provably the same predicate)."""
+    ) -> Optional["WindowBoundary"]:
+        """Def 3.4's boundary correspondence for ``win``: its operators on
+        each side, its sink pairs, and whether they are the versions' sinks;
+        ``None`` when the window is ill-formed.  ``assume_connected=True``
+        skips the Def 3.1 connectivity recheck — the ``WindowTable`` has
+        already established it via ``mask_connected`` (provably the same
+        predicate)."""
+        self.windows_admitted += 1
         fwd = self.mapping.forward
         bwd = self.mapping.backward
         p_in = self.p_ops(win)
@@ -487,6 +531,10 @@ class VersionPair:
         # producers must be unmodified (equal output semantics on both sides)
         for s in p_srcs:
             if self.P.ops[s].signature() != self.Q.ops[fwd[s]].signature():
+                return None
+        # a symbolic source must not take the id of a window operator
+        for s in p_srcs:
+            if f"{_SOURCE_PREFIX}{s}" in p_in or f"{_SOURCE_PREFIX}{s}" in q_in:
                 return None
 
         # ---- out-boundary consumer ports
@@ -537,29 +585,52 @@ class VersionPair:
         sink_pairs.extend(boundary_pairs)
         if not sink_pairs:
             return None
+        return WindowBoundary(p_in, q_in, tuple(sink_pairs), at_version_sink)
 
-        # ---- build the two sub-DAGs with shared symbolic sources
-        P_sub = self._induce_with_sources(self.P, p_in, self.schemas_p, side="p")
-        Q_sub = self._induce_with_sources(self.Q, q_in, self.schemas_q, side="q")
-        if P_sub is None or Q_sub is None:
-            return None
-        return QueryPair(
-            P_sub,
-            Q_sub,
-            tuple(sink_pairs),
-            semantics=self.semantics,
-            at_version_sink=at_version_sink,
+    def _window_inputs(
+        self, side: str
+    ) -> Dict[str, Tuple[Tuple[Link, Optional[Link], Optional[Operator]], ...]]:
+        """Every operator's in-links in port order, each with the link from
+        the symbolic source that stands in for its producer in a window the
+        producer lies outside of, and that source (Def 3.4).  The source is
+        named by the P-side id of the producer pair (``None`` for an
+        inserted Q-side producer, which no well-formed window cuts), one
+        object per input and side for all windows, so its memoized
+        signature serves every window's fingerprint."""
+        dag, schemas = (self.P, self.schemas_p) if side == "p" else (self.Q, self.schemas_q)
+        bwd = self.mapping.backward
+        sources: Dict[str, Operator] = {}
+        out = {}
+        for op_id, links in dag.in_links.items():
+            picks = []
+            for l in links:
+                canonical = l.src if side == "p" else bwd.get(l.src)
+                if canonical is None:
+                    picks.append((l, None, None))
+                    continue
+                sym_id = f"{_SOURCE_PREFIX}{canonical}"
+                sym = sources.get(sym_id)
+                if sym is None:
+                    sym = sources[sym_id] = Operator.make(
+                        sym_id, D.SOURCE, schema=tuple(schemas[l.src])
+                    )
+                picks.append((l, Link(sym_id, l.dst, l.dst_port), sym))
+            out[op_id] = tuple(picks)
+        return out
+
+    def _sub_dags(self, p_in: Set[str], q_in: Set[str]) -> Tuple[DataflowDAG, DataflowDAG]:
+        """The two sub-DAGs of a well-formed window, with shared symbolic
+        sources (counted in ``subdags``)."""
+        with self._subdags_lock:
+            self.subdags += 1
+        return (
+            self._induce_with_sources(p_in, "p"),
+            self._induce_with_sources(q_in, "q"),
         )
 
-    def _induce_with_sources(
-        self,
-        dag: DataflowDAG,
-        inside: Set[str],
-        schemas: Mapping[str, List[str]],
-        side: str,
-    ) -> Optional[DataflowDAG]:
-        fwd = self.mapping.forward
-        bwd = self.mapping.backward
+    def _induce_with_sources(self, inside: Set[str], side: str) -> DataflowDAG:
+        dag = self.P if side == "p" else self.Q
+        inputs = self._inputs[side]
         # sorted: the induced sub-DAG's operator/link order (and so the
         # serialized certificate payload) must not follow set iteration order
         ordered = sorted(inside)
@@ -567,31 +638,126 @@ class VersionPair:
         links = [l for l in dag.links if l.src in inside and l.dst in inside]
         extra_ops: Dict[str, Operator] = {}
         for op_id in ordered:
-            for l in dag.in_links[op_id]:
-                if l.src in inside:
-                    continue
-                # symbolic source named by the P-side id of the producer pair
-                canonical = l.src if side == "p" else bwd[l.src]
-                sym_id = f"__in__{canonical}"
-                if sym_id not in extra_ops:
-                    # one object per input and side for all windows, so its
-                    # memoized signature serves every window's fingerprint
-                    sym = self._symbolic.get((side, sym_id))
-                    if sym is None:
-                        sym = self._symbolic[(side, sym_id)] = Operator.make(
-                            sym_id, D.SOURCE, schema=tuple(schemas[l.src])
-                        )
-                    extra_ops[sym_id] = sym
-                links.append(Link(sym_id, l.dst, l.dst_port))
+            for l, sym_link, sym in inputs[op_id]:
+                if l.src not in inside:
+                    extra_ops.setdefault(sym.id, sym)
+                    links.append(sym_link)
         # no ``validate()``: the pair validated ``P`` and ``Q``, and a window
         # keeps every input port of its operators (a producer outside it
         # becomes a symbolic source), so the sub-DAG is acyclic with the
         # version's arities and ports; the constructor still refuses a
         # duplicate id or a dangling link
-        try:
-            return DataflowDAG(ops + list(extra_ops.values()), links)
-        except D.DAGError:
-            return None
+        return DataflowDAG(ops + list(extra_ops.values()), links)
+
+
+_SOURCE_PREFIX = "__in__"  # a window's symbolic source: ``__in__<P-side producer id>``
+
+
+class WindowBoundary(NamedTuple):
+    """``VersionPair.window_boundary``'s answer for a well-formed window."""
+
+    p_in: Set[str]
+    q_in: Set[str]
+    sink_pairs: Tuple[Tuple[str, str], ...]
+    at_version_sink: bool
+
+
+class _WindowSides:
+    """What a window's two ``WindowDAG``s share: its operators on each side
+    and, once something asks for them, its two sub-DAGs."""
+
+    __slots__ = ("pair", "inside", "dags")
+
+    def __init__(self, pair: VersionPair, p_in: Set[str], q_in: Set[str]):
+        self.pair = pair
+        self.inside = {"p": p_in, "q": q_in}
+        self.dags: Optional[Dict[str, DataflowDAG]] = None
+
+    def materialize(self, side: str) -> DataflowDAG:
+        if self.dags is None:
+            p_sub, q_sub = self.pair._sub_dags(self.inside["p"], self.inside["q"])
+            self.dags = {"p": p_sub, "q": q_sub}
+        return self.dags[side]
+
+
+class WindowDAG:
+    """One side of a window's query pair, read through its version's DAG.
+
+    It answers what ``QueryPair.fingerprint`` and the EVs read of a
+    ``DataflowDAG`` — ``ops``, ``in_links``, ``out_links``, ``links`` — with
+    exactly what ``VersionPair._induce_with_sources`` builds: the window's
+    operators in id order, then one symbolic source for each producer
+    outside the window in order of first use, and each input port fed by
+    its own link or by its source's (``VersionPair._window_inputs``).
+    ``ops`` and ``in_links``, which every reader walks, are made up front;
+    ``links`` and ``out_links`` on first read.  The rest of the
+    ``DataflowDAG`` interface (``topo_order``, ``sources``, ``signature``,
+    ...) comes from the sub-DAG itself, built with its other side on first
+    use; a certificate asks for both through ``QueryPair.materialized``.
+    """
+
+    __slots__ = ("_sides", "_side", "ops", "in_links", "_out_links", "_links")
+
+    def __init__(self, sides: _WindowSides, side: str):
+        self._sides = sides
+        self._side = side
+        pair = sides.pair
+        inside = sides.inside[side]
+        dag_ops = (pair.P if side == "p" else pair.Q).ops
+        inputs = pair._inputs[side]
+        ops: Dict[str, Operator] = {}
+        in_links: Dict[str, List[Link]] = {}
+        sources: Dict[str, Operator] = {}
+        for op_id in sorted(inside):
+            ops[op_id] = dag_ops[op_id]
+            in_links[op_id] = ins = []
+            for l, sym_link, sym in inputs[op_id]:
+                if l.src in inside:
+                    ins.append(l)
+                else:
+                    ins.append(sym_link)
+                    sources[sym.id] = sym
+        for sym_id, sym in sources.items():
+            ops[sym_id] = sym
+            in_links[sym_id] = []
+        self.ops = ops
+        self.in_links = in_links
+        self._out_links: Optional[Dict[str, List[Link]]] = None
+        self._links: Optional[List[Link]] = None
+
+    def materialize(self) -> DataflowDAG:
+        return self._sides.materialize(self._side)
+
+    def __getattr__(self, name: str):
+        if name.startswith("__"):
+            raise AttributeError(name)
+        return getattr(self.materialize(), name)
+
+    @property
+    def links(self) -> List[Link]:
+        """Links inside the window in the version's order, then the
+        symbolic sources' in operator and port order."""
+        if self._links is None:
+            side = self._side
+            inside = self._sides.inside[side]
+            pair = self._sides.pair
+            dag = pair.P if side == "p" else pair.Q
+            links = [l for l in dag.links if l.src in inside and l.dst in inside]
+            for op_id in sorted(inside):
+                for l in self.in_links[op_id]:
+                    if l.src not in inside:
+                        links.append(l)
+            self._links = links
+        return self._links
+
+    @property
+    def out_links(self) -> Dict[str, List[Link]]:
+        if self._out_links is None:
+            out: Dict[str, List[Link]] = {i: [] for i in self.ops}
+            for l in self.links:
+                out[l.src].append(l)
+            self._out_links = out
+        return self._out_links
 
 
 _UNSET = object()  # WindowTable lazy-slot sentinel (None is a valid value)
@@ -610,9 +776,11 @@ class WindowTable:
         ``units``), and the popcount;
       * ``neighbor_mask(id)`` — the frontier mask (lazy);
       * ``connected(id)`` — Def 3.1 connectivity via mask fixpoint (lazy);
-      * ``query_pair(id)`` / ``fingerprint(id)`` — the exported Def 3.4
-        query pair and its canonical content address (lazy; ``None`` for
-        ill-formed windows);
+      * ``boundary(id)`` — Def 3.4's boundary correspondence, all that
+        admission asks of a window (lazy; ``None`` for ill-formed windows);
+      * ``query_pair(id)`` / ``fingerprint(id)`` — the window's query pair,
+        read through the version DAGs (``WindowDAG``), and its canonical
+        content address (lazy; ``None`` for ill-formed windows);
       * ``covered_mask(id)`` — bit *c* set iff change *c*'s required units
         are inside the window (lazy);
       * ``valid[id]`` — storage slot for the per-EV-roster validity tuple
@@ -624,7 +792,7 @@ class WindowTable:
 
     __slots__ = (
         "pair", "_ids", "masks", "key", "pop", "valid",
-        "_neighbors", "_connected", "_qp", "_fp", "_covered",
+        "_neighbors", "_connected", "_boundary", "_qp", "_fp", "_covered",
     )
 
     def __init__(self, pair: "VersionPair"):
@@ -636,6 +804,7 @@ class WindowTable:
         self.valid: List[Optional[Tuple[int, ...]]] = []
         self._neighbors: List[Optional[int]] = []
         self._connected: List[Optional[bool]] = []
+        self._boundary: List[object] = []
         self._qp: List[object] = []
         self._fp: List[object] = []
         self._covered: List[Optional[int]] = []
@@ -655,6 +824,7 @@ class WindowTable:
             self.valid.append(None)
             self._neighbors.append(None)
             self._connected.append(None)
+            self._boundary.append(_UNSET)
             self._qp.append(_UNSET)
             self._fp.append(_UNSET)
             self._covered.append(None)
@@ -682,15 +852,21 @@ class WindowTable:
             self._connected[wid] = c
         return c
 
+    def boundary(self, wid: int) -> Optional[WindowBoundary]:
+        b = self._boundary[wid]
+        if b is _UNSET:
+            if not self.connected(wid):
+                b = None
+            else:
+                b = self.pair.window_boundary(self.frozen(wid), assume_connected=True)
+            self._boundary[wid] = b
+        return b
+
     def query_pair(self, wid: int) -> Optional[QueryPair]:
         qp = self._qp[wid]
         if qp is _UNSET:
-            if not self.connected(wid):
-                qp = None
-            else:
-                qp = self.pair._build_query_pair(
-                    self.frozen(wid), assume_connected=True
-                )
+            b = self.boundary(wid)
+            qp = None if b is None else self.pair.window_query_pair(b)
             self._qp[wid] = qp
         return qp
 

@@ -409,7 +409,8 @@ class VersionChainSession:
             )
             sp.set_metadata(verdict=VERDICT_NAMES[verdict], reused=int(reused),
                             decompositions=stats.decompositions_explored,
-                            ev_calls=stats.ev_calls, sat_calls=stats.sat_calls)
+                            ev_calls=stats.ev_calls, sat_calls=stats.sat_calls,
+                            windows=stats.windows_admitted, subdags=stats.subdags)
         exec_stats = frontier = results = None
         if plan is not None:
             if self.exec_mode == "full":
